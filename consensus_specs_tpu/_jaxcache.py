@@ -1,4 +1,5 @@
-"""Persistent XLA compilation cache setup, shared by the kernel modules.
+"""JAX process setup shared by the entry points and kernel modules: the
+persistent XLA compilation cache, and the host backend beside a chip.
 
 The heavy kernels (batched pairing, epoch deltas) cost minutes of XLA
 compile per shape; the persistent cache makes that once-per-machine.
@@ -31,3 +32,15 @@ def configure() -> None:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
     except OSError:  # read-only checkout: in-memory cache only
         pass
+
+
+def keep_host_backend() -> None:
+    """Keep JAX's CPU backend beside an accelerator: the host-placed
+    kernels (epoch deltas, KZG MSM) run there, and their device choice
+    raises when it is missing.  A ``JAX_PLATFORMS`` that names only
+    accelerators (a chip machine may set ``tpu``) gets ``cpu`` appended;
+    the first platform stays the default.  Call before JAX initializes
+    its backends."""
+    platforms = os.environ.get("JAX_PLATFORMS")
+    if platforms and "cpu" not in platforms.split(","):
+        os.environ["JAX_PLATFORMS"] = platforms + ",cpu"

@@ -11,7 +11,7 @@ def main(argv=None):
     from consensus_specs_tpu.gen.runners import ensure_vector_sources_importable
 
     ensure_vector_sources_importable()
-    # reference handler taxonomy (tests/generators/fork_choice/main.py):
+    # reference handler classification (tests/generators/fork_choice/main.py):
     # get_head / on_block / ex_ante, plus on_merge_block from bellatrix
     mods = {
         "get_head": ["tests.spec.phase0.test_fork_choice",

@@ -14,7 +14,7 @@ def main(argv=None):
     ensure_vector_sources_importable()
     from consensus_specs_tpu.testing.helpers.constants import ALL_PRE_POST_FORKS
 
-    # Reference taxonomy (tests/generators/transition/main.py): EVERY
+    # Reference classification (tests/generators/transition/main.py): EVERY
     # module emits under handler "core", for every pre/post fork pair.
     modules = (
         "tests.spec.altair.test_transition",

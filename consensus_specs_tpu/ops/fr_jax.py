@@ -198,7 +198,6 @@ def ntt_device(values: Sequence[int], inv: bool = False) -> List[int]:
 def sharded_ntt(values: Sequence[int], mesh, axis_name: str = None) -> List[int]:
     """NTT of ``values`` sharded over ``mesh``'s devices along the chunk
     axis; returns canonical ints, bit-exact vs crypto.fr.fft."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     if axis_name is None:
@@ -253,7 +252,7 @@ def sharded_ntt(values: Sequence[int], mesh, axis_name: str = None) -> List[int]
         return jnp.stack(digits, axis=-1)[None]
 
     spec_sharded = NamedSharding(mesh, P(axis_name))
-    fn = shard_map(
+    fn = jax.shard_map(
         _shard_body, mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P(axis_name)),
         out_specs=P(axis_name))

@@ -147,12 +147,13 @@ def hash_layer(blocks: List[bytes]) -> List[bytes]:
 
 # -- whole-wave-schedule hashing (single device program) --------------------
 #
-# Per-layer dispatch pays one host<->device round trip per tree level —
-# ruinous when the link is a tunnel and latency/bandwidth dominate.  The
-# TPU-native shape for a full merkle (sub)tree is ONE program: upload the
-# known child digests once, run every wave as a gather + compress stage
-# inside a single jit (the level loop is unrolled at trace time — wave
-# sizes are static), download every produced digest once.
+# Per-layer dispatch pays one host<->device round trip per tree level;
+# what that costs against the whole-tree program is not measured on the
+# chip yet.  The TPU-native shape for a full merkle (sub)tree is ONE
+# program: upload the known child digests once, run every wave as a
+# gather + compress stage inside a single jit (the level loop is unrolled
+# at trace time — wave sizes are static), download every produced digest
+# once.
 
 
 def _run_waves(known, lefts, rights):

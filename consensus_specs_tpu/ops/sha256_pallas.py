@@ -8,10 +8,12 @@ unrolled rounds for its tile plus the padding-block compression (the
 merkle case: one 64-byte message = two child roots).
 
 On non-TPU backends the kernel runs in interpreter mode — bit-identical
-but minutes-per-shape slow under this image's jax build, so the
-differential tests (tests/test_sha256_pallas.py) auto-skip off-TPU and
-opt in via CSTPU_PALLAS_TESTS=1.  Registered as the "pallas" hashing
-backend: ``hashing.set_backend("pallas")``.
+but minutes-per-shape slow under this image's jax build, so its
+interpret-mode tests (tests/test_sha256_pallas.py) are opt-in via
+CSTPU_PALLAS_TESTS=1.  tests/test_chip_compile.py compiles the kernel for
+a described TPU v5e, and chip_smoke.py phase f runs it on the chip.
+Registered as the "pallas" hashing backend:
+``hashing.set_backend("pallas")``.
 """
 from __future__ import annotations
 
@@ -88,10 +90,16 @@ def _block64_t_impl(words_t: jnp.ndarray) -> jnp.ndarray:
         _kernel,
         out_shape=jax.ShapeDtypeStruct((8, n), jnp.uint32),
         grid=(n // _LANES,),
-        in_specs=[pl.BlockSpec((16, _LANES), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((8, _LANES), lambda i: (0, i)),
+        in_specs=[pl.BlockSpec((16, _LANES), _block_index)],
+        out_specs=pl.BlockSpec((8, _LANES), _block_index),
         interpret=_use_interpret(),
     )(words_t)
+
+
+def _block_index(i):
+    # an int32 block index even under jax_enable_x64 (the epoch and BLS
+    # modules turn it on): Mosaic refuses a literal 0 traced as i64
+    return jnp.int32(0), i
 
 
 # On real TPUs the kernel compiles natively and the jit wrapper caches the

@@ -23,7 +23,6 @@ import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from consensus_specs_tpu.ops.bls_jax import pairing
-from consensus_specs_tpu.ops.jax_compat import shard_map
 
 # compiled per (mesh, axis): jit keys on callable identity, so a fresh
 # wrapper per call would recompile the Miller-loop pipeline every time
@@ -48,7 +47,7 @@ def make_sharded_pairs_check(mesh: Mesh, axis: str = "v"):
         return pairing.final_exp_is_one_traced(f)
 
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(None, axis), P(None, axis),
@@ -57,7 +56,7 @@ def make_sharded_pairs_check(mesh: Mesh, axis: str = "v"):
             # the Miller loop's fori_loop carries have no replication
             # rule; every in/out spec is explicit so nothing rides on the
             # checker
-            check_rep=False,
+            check_vma=False,
         )
     )
     _SHARDED_CHECK_CACHE[key] = fn
@@ -154,14 +153,14 @@ def make_sharded_lane_partials(mesh: Mesh, axis: str = "v"):
         return fn
 
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             pairing._miller_product,
             mesh=mesh,
             in_specs=(P(None, axis), P(None, axis),
                       P(None, axis), P(None, axis)),
             out_specs=P(axis),
             # same fori_loop-carry caveat as make_sharded_pairs_check
-            check_rep=False,
+            check_vma=False,
         )
     )
     _SHARDED_PARTIALS_CACHE[key] = fn

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from consensus_specs_tpu.ops.jax_compat import shard_map
 from consensus_specs_tpu.ops.sha256_jax import sha256_block64
 
 jax.config.update("jax_enable_x64", True)
@@ -68,7 +67,7 @@ def make_sharded_epoch_step(mesh: Mesh, axis: str = "v"):
         -> (new_balances, layer_digests)
     """
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P(axis),
                   P(axis), P(axis), P()),

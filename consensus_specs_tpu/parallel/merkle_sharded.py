@@ -50,12 +50,10 @@ def make_sharded_subtree_roots(mesh: Mesh, axis: str = "v"):
     """jitted fn: sharded [n] balances -> [n_dev, 8] per-shard subtree
     roots (still device-resident; axis-sharded input, replicated output).
     Cached per (mesh, axis) so repeated roots reuse the compiled kernel."""
-    from jax.experimental.shard_map import shard_map
-
     key = (mesh, axis)
     fn = _SUBTREE_FN_CACHE.get(key)
     if fn is None:
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda b: _local_subtree_root(b)[None, :],
             mesh=mesh,
             in_specs=P(axis),

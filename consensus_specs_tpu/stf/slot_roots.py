@@ -49,20 +49,18 @@ def _maybe_resident_balances_root(state) -> None:
     device = merkle_resident.resident_device()
     if device is None:
         return
-    try:
-        from . import columns
+    from . import columns
 
-        resident = merkle_resident.ResidentPackedU64List(
-            type(balances).LENGTH, device=device)
-        # resident-column read (ISSUE 10): after the epoch transition's
-        # flush this is the identity fast path — no tree walk before the
-        # device upload
-        resident.upload(columns.balance_column(state).astype("u8"))
-        merkle_resident.memoize_packed_u64_contents_root(
-            balances, resident.contents_subtree_root())
-        tracing.count("stf.resident_slot_root")
-    except Exception:  # device flake: the host path is always correct
-        tracing.count("stf.resident_slot_root_failed")
+    # a device error raises: the block engine rolls the block back and
+    # replays it literally (counted in stf.stats["replayed_blocks"])
+    resident = merkle_resident.ResidentPackedU64List(
+        type(balances).LENGTH, device=device)
+    # resident-column read (ISSUE 10): after the epoch transition's flush
+    # this is the identity fast path — no tree walk before the device upload
+    resident.upload(columns.balance_column(state).astype("u8"))
+    merkle_resident.memoize_packed_u64_contents_root(
+        balances, resident.contents_subtree_root())
+    tracing.count("stf.resident_slot_root")
 
 
 def process_slots(spec, state, slot) -> None:

@@ -43,7 +43,7 @@ def bad(n, arr):
 
 _SHARD_MAP = """\
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 def step(x):
     x[:] = 0

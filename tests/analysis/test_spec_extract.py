@@ -37,6 +37,32 @@ def test_digest_changes_on_semantic_edit():
     assert a.get("phase0", "f").digest != b.get("phase0", "f").digest
 
 
+_NESTED = ("def f(x):\n    def g(y):\n        assert y > 0\n        return y\n"
+           "    return g(x)\n")
+
+
+def test_digest_of_nested_def_is_pinned_across_interpreters():
+    # the nested def carries 3.12's empty ``type_params``; the digest is the
+    # one every interpreter must produce (the live pins depend on it)
+    fn = _snap(_NESTED).get("phase0", "f")
+    assert fn.digest == (
+        "4f6f03539deae190afb98f84f9292ff5337953694e29b2922e05b7f959ba5d5f")
+    assert fn.raise_digest == (
+        "46d30ef3ca3610b8a390e7bbf268c7e22840d29a4c615a89691f3e0823c7da46")
+
+
+def test_canonical_dump_omits_empty_schema_fields():
+    import ast
+
+    node = ast.parse(_NESTED).body[0].body[0]
+    with_field = spec_extract._canonical(node)
+    node.type_params = []
+    assert spec_extract._canonical(node) == with_field
+    del node.type_params
+    assert spec_extract._canonical(node) == with_field
+    assert "type_params" not in with_field
+
+
 def test_raise_sites_are_ordered_and_digested():
     snap = _snap(
         "def f(x):\n"

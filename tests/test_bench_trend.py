@@ -6,6 +6,8 @@ import json
 import os
 import sys
 
+import pytest
+
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import bench
@@ -536,3 +538,30 @@ def test_analyzer_refusal_stale_only():
               "justification": "gone"}])
     assert "1 unbaselined" in line
     assert "stale baseline entry in y.py" in line
+
+
+def test_bench_start_up_requires_a_tpu_unless_pinned_to_cpu(monkeypatch):
+    # the test process runs JAX on the CPU: no chip, so the benchmark
+    # refuses to start — unless the caller pinned JAX_PLATFORMS=cpu
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    bench._require_chip()
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(SystemExit, match="not a TPU"):
+        bench._require_chip()
+
+
+@pytest.mark.parametrize("given, kept", [
+    ("tpu", "tpu,cpu"),          # a chip machine's pin: the host backend joins
+    ("tpu,cpu", "tpu,cpu"),
+    ("cpu", "cpu"),              # a host rehearsal stays on the host
+    (None, None),                # unset: JAX brings up every backend itself
+])
+def test_keep_host_backend(monkeypatch, given, kept):
+    from consensus_specs_tpu import _jaxcache
+
+    if given is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", given)
+    _jaxcache.keep_host_backend()
+    assert os.environ.get("JAX_PLATFORMS") == kept

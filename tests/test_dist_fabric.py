@@ -114,6 +114,16 @@ def test_worker_scope_reaches_the_worker_process():
     assert procs == {"proc1", "proc2"}  # round-robin touched both
 
 
+def test_worker_env_pins_jax_to_cpu_unconditionally(monkeypatch):
+    """A chip belongs to one process: a coordinator started on the TPU
+    platform (or handing one in through ``env``) still gets CPU workers."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    fab = Fabric(n_workers=1, env={"JAX_PLATFORMS": "tpu"})
+    env = fab._worker_env(fab._workers[0])
+    assert env["JAX_PLATFORMS"] == "cpu"
+    assert env["CSTPU_DIST_PROC"] == "proc1"
+
+
 def test_coordinator_wears_proc0_scope_inside_fabric_extent():
     from consensus_specs_tpu import faults
 

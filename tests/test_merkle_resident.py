@@ -153,3 +153,67 @@ def test_resident_splice_into_state_root():
     host = state.copy()
     bulk.set_packed_uint64_from_numpy(host.balances, balances + np.uint64(5))
     assert merkle_root(spliced) == bytes(host.hash_tree_root())
+
+
+def test_resident_device_raises_when_device_init_fails(monkeypatch):
+    # no silent host fallback: a device that fails to come up is an error
+    import jax
+
+    from consensus_specs_tpu.ops import merkle_resident
+
+    def broken():
+        raise RuntimeError("device init failed")
+
+    monkeypatch.setattr(jax, "devices", broken)
+    for mode in ("auto", "1"):
+        monkeypatch.setenv("CSTPU_RESIDENT_MERKLE", mode)
+        with pytest.raises(RuntimeError, match="device init failed"):
+            merkle_resident.resident_device()
+
+
+@pytest.mark.parametrize("module, pick, var", [
+    ("consensus_specs_tpu.ops.epoch_jax", "_kernel_device",
+     "CSTPU_EPOCH_BACKEND"),
+    ("consensus_specs_tpu.ops.kzg_jax", "_msm_device", "CSTPU_KZG_BACKEND"),
+])
+def test_backend_choice_raises_on_missing_backend(monkeypatch, module, pick,
+                                                  var):
+    import importlib
+
+    pick_device = getattr(importlib.import_module(module), pick)
+    monkeypatch.delenv(var, raising=False)
+    assert pick_device().platform == "cpu"  # the unmeasured default
+    monkeypatch.setenv(var, "no_such_backend")
+    with pytest.raises(RuntimeError, match="no_such_backend"):
+        pick_device()
+
+
+def test_slot_root_device_error_propagates(monkeypatch):
+    # a failing resident upload raises out of the per-slot root, so the
+    # block engine's rollback + counted literal replay handles it
+    from consensus_specs_tpu.ops import merkle_resident
+    from consensus_specs_tpu.specs.builder import get_spec
+    from consensus_specs_tpu.ssz import bulk
+    from consensus_specs_tpu.stf import slot_roots
+    from consensus_specs_tpu.testing.context import (
+        default_activation_threshold,
+        default_balances,
+    )
+    from consensus_specs_tpu.testing.helpers.genesis import create_genesis_state
+
+    spec = get_spec("phase0", "minimal")
+    state = create_genesis_state(
+        spec, default_balances(spec), default_activation_threshold(spec))
+    values = bulk.packed_uint64_to_numpy(state.balances) + 1
+    monkeypatch.setenv("CSTPU_RESIDENT_MERKLE", "1")
+    monkeypatch.setattr(merkle_resident, "RESIDENT_MIN", 1)
+
+    def broken(self, values):
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(merkle_resident.ResidentPackedU64List, "upload",
+                        broken)
+    bulk.set_packed_uint64_from_numpy(state.balances, values)
+    assert state.balances.get_backing().left._root is None
+    with pytest.raises(RuntimeError, match="device lost"):
+        slot_roots.state_root(spec, state)

@@ -151,12 +151,23 @@ def test_newest_snapshot_pair_prefers_prev_file(tmp_path):
     assert prev["epoch_e2e_bls"]["value"] == 2.0
 
 
-def test_newest_snapshot_pair_falls_back_to_git_history():
-    # the live repo: BENCH_DETAILS.json has committed history, so the
-    # fallback finds a differing previous version (or a PREV file once
-    # bench has run) — either way the pair is comparable
-    cur, prev, label = perf_doctor.newest_snapshot_pair()
-    assert isinstance(cur, dict)
-    if prev is not None:
-        assert label in ("BENCH_DETAILS_PREV.json", "git history")
-        assert isinstance(prev, dict)
+def test_newest_snapshot_pair_falls_back_to_git_history(tmp_path):
+    # a repo whose BENCH_DETAILS.json has committed history and no PREV
+    # file: the fallback finds the newest differing committed version
+    import subprocess
+
+    def git(*args):
+        subprocess.run(["git", "-c", "user.name=t", "-c", "user.email=t@t",
+                        *args], cwd=tmp_path, check=True,
+                       capture_output=True)
+
+    details = tmp_path / "BENCH_DETAILS.json"
+    git("init", "-q")
+    details.write_text(json.dumps({"epoch_e2e_bls": _row(2.0)}))
+    git("add", "BENCH_DETAILS.json")
+    git("commit", "-q", "-m", "first")
+    details.write_text(json.dumps({"epoch_e2e_bls": _row(3.0)}))
+    cur, prev, label = perf_doctor.newest_snapshot_pair(str(tmp_path))
+    assert label == "git history"
+    assert cur["epoch_e2e_bls"]["value"] == 3.0
+    assert prev["epoch_e2e_bls"]["value"] == 2.0

@@ -121,9 +121,9 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "state_transition", _MAINLINE,
-                "bb8fdce127f670d374f9f7313aaa4599c29404713eb3d2b9b577fc979d90e09b",
+                "7a87eac890f30b7675bf6ca56e4f2b941fed41cb47471f8a9e73f5598f207ad0",
                 2,
-                "3daf41152d6c2fe0f13de6bdb515d60d20930f02d9b18b98cffa5eadf7e70f5c",
+                "78f7f952fafd71bdd1d5b78a2fbc4f7c4c7da2522ca1938d7405c9ade02a9a9b",
                 ("invalid signature (batch entry",
                  "state root mismatch")),
         ),
@@ -137,7 +137,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "verify_block_signature", _MAINLINE,
-                "91b8a5007f422e3a88d7c45f7d12cb730f16c5fdca10055339908b03abc666a0",
+                "f04d50e632ca38a4c02ff714b53ba4fa8e20e7d2be2fe4e1297ef7b450384872",
                 0, _NO_RAISES, ()),
         ),
         description="verify_block_signature as one deferred batch entry; "
@@ -150,9 +150,9 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "process_block_header", _MAINLINE,
-                "dda1eb99d09bb7ab8284d8788bd0704e1e8578df842257fdf158156f78144270",
+                "793e75220920fc588a5888b0ba017d7eb181de59b945879719c422560a652848",
                 5,
-                "3b29d00dbe32f4a407bd77ee1f4534096c3c2b777b6acc599771bd527bbefb49",
+                "7b3da13de88549da45f0a11092f16d887e4b672363b5350494cd7a9371b44d24",
                 ("assert block.slot == state.slot",
                  "assert block.slot > state.latest_block_header.slot",
                  "assert block.proposer_index == beacon_proposer_index(spec, state)",
@@ -169,9 +169,9 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "process_randao", _MAINLINE,
-                "a93f7b5e4909da265be1f438625c246b1be357870fe6a7909963fa9fde7bc728",
+                "e85059a9f2f1b39545c8e8a0ed41f9e7c60590ecb884e94618137739b078ee9d",
                 1,
-                "9421816e1b99c5107c5a56edca86ef467837b6ae1b6a66ecfc9e80d92d62dbcf",
+                "4dccfd979a4af30941380afc3b02e12c744293e3bf2dbeba4da7e71b80d9434e",
                 (None,)),
         ),
         description="process_randao with the reveal's pairing check "
@@ -185,9 +185,9 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "process_operations", _MAINLINE,
-                "414346eba84a6df9c095b73466127afcddff53d64893d51daf87c32d91dc36c9",
+                "99da53dcefb9e624b16c9e256bbc00042f8f7086ab4e663c4da85a7675ee454e",
                 1,
-                "036c5bf30990a6ea193e9b8ce778d8e9eaecac302e724012a37426a65625562d",
+                "4f198dcc492a8114d1dae7ff354d549eafbd824d21edc06c76dabf9283b67d1d",
                 ("assert len(body.deposits) == min(",)),
         ),
         description="process_operations with the attestation loop swapped "
@@ -201,9 +201,9 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "process_attestation", ("phase0",),
-                "e535d8d21bb00209dc1ab5ba9ec3956add1a99ea27cbb657fdf98affabcdee33",
+                "654b5468657a0a8299dd26b07af932cff8a79db1083736c78aff22e94328113b",
                 8,
-                "8b167700ccd6c36f942edd1b1613a4fbe3a07f4efaceef039dc1099780d30190",
+                "a9694889662a8a789cd234f6ee2b0da7f2b772a56333864e47f86da2f18df765",
                 (None, None, None, None, None,
                  "source != current justified",
                  "source != previous justified",
@@ -221,9 +221,9 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "process_attestation", ("altair", "bellatrix"),
-                "f68c9cabb76a1fe7ebff6aef2a13a5677773948f6fe1e017126e00aa8c3047df",
+                "55fbc8605211de2947e1279200fb5529594021d8cb16417c8bebdd68a3e136c2",
                 6,
-                "33390d2f614e0f8dd592ab43c2082b018f6067323dbafadafaead1697d5af7ea",
+                "20ddbb76ef88f04e1f0d7399a4ea663cb199f13442d7f3e72e9970715da91c2b",
                 (None, None, None, None, None, None)),
         ),
         description="altair-lineage process_attestation vectorized over "
@@ -239,9 +239,9 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
             SpecPin(
                 "get_attestation_participation_flag_indices",
                 ("altair", "bellatrix"),
-                "40a00349b84a8e119549c159f8e7252254f4b1bb3faa52b233f27f1a818d4f5c",
+                "54d35d7bcc27cc3c17f1a97f970775598cd625cc1a19790fcbfe72be715e49e8",
                 1,
-                "e1fea472018d789c02435f27a70e7cfed56be59527d2df4d872cf86e29423d02",
+                "f4318883418141630910c4914c2addd665652c6a31a166d5ab9d7c1c748687c5",
                 ("source != justified checkpoint",)),
         ),
         description="get_attestation_participation_flag_indices as a "
@@ -256,9 +256,9 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "process_slots", _MAINLINE,
-                "20f2c2bf06e07bca625334381ea68606c05dfe660f2206332eac577289e8641a",
+                "0fd11f7afb4c1eb13cafebaef9182c19734cda90f1e9a7acf3e984884b34d123",
                 1,
-                "51049c89e70ec2abee5491a5e71a7684ac58e4aa4b88ed51f2883d601d55e550",
+                "335acb1d37cd4d9e3eb4739a6bb7396d7a2cec056e4f5d29e9714176b102113a",
                 ("assert state.slot < slot",)),
         ),
         description="process_slots with bulk root hashing; the slot "
@@ -271,7 +271,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "process_slot", _MAINLINE,
-                "eecfd249a8bd48d5a928a2262be40df0a514d38a448907dd8a5b2551de5c3a61",
+                "f7e9bc528cf240abe1a11b4704632a0be581246ea7217e991a77ad67f2fda36b",
                 0, _NO_RAISES, ()),
         ),
         description="process_slot's three root writes off the bulk "
@@ -285,13 +285,13 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "get_beacon_proposer_index", _MAINLINE,
-                "913ee070c10992c4187b0af9700c62e21dd1bed2b0516693ffe27e9deb244c3e",
+                "80f18635ff69aa84bd45c7873438914701562cb6154f57efd5003547154ef74b",
                 0, _NO_RAISES, ()),
             SpecPin(
                 "compute_proposer_index", _MAINLINE,
-                "5dcbb20c3c7be365b80b3cec66aca598d1b0b6cd507e3f5c682a8a927a569bb1",
+                "9efe34df547642c9724160474a5cc425ba3921fbabd9d4fa823c9046a4824d17",
                 1,
-                "d6e65d181e9024e6c15ddd7e6ea9046eef30a44751168b334d651973a0b17012",
+                "3b6e21f88e330b9ea3d21754b84a67b05bf6d7ec46adf21efb5279a0cfae6b7f",
                 ("assert total > 0",)),
         ),
         description="get_beacon_proposer_index + compute_proposer_index's "
@@ -304,11 +304,11 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "get_beacon_committee", _MAINLINE,
-                "44dc1abfbb33fd035d4d902a73b688c4d203e1a3af7ccd97cbb3784415d9fb77",
+                "57b88171e91fbf24ffb4f47e4dac08ff708ca249a72e97074e151af1ab467324",
                 0, _NO_RAISES, ()),
             SpecPin(
                 "compute_committee", _MAINLINE,
-                "fb1ca571347798d66ad297ed49a5dc831187744aec393d0d80df92486b2c9610",
+                "aed4517144e5f175520771da6108e78998707bd44b2b85f52b1d896be9bdfc24",
                 0, _NO_RAISES, ()),
         ),
         description="per-epoch committee geometry: one whole-permutation "
@@ -321,9 +321,9 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "process_attestation", ("phase0",),
-                "e535d8d21bb00209dc1ab5ba9ec3956add1a99ea27cbb657fdf98affabcdee33",
+                "654b5468657a0a8299dd26b07af932cff8a79db1083736c78aff22e94328113b",
                 8,
-                "8b167700ccd6c36f942edd1b1613a4fbe3a07f4efaceef039dc1099780d30190",
+                "a9694889662a8a789cd234f6ee2b0da7f2b772a56333864e47f86da2f18df765",
                 ("target epoch outside window",
                  "target epoch != epoch of slot",
                  "inclusion window",
@@ -332,9 +332,9 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
                  None, None, None)),
             SpecPin(
                 "process_attestation", ("altair", "bellatrix"),
-                "f68c9cabb76a1fe7ebff6aef2a13a5677773948f6fe1e017126e00aa8c3047df",
+                "55fbc8605211de2947e1279200fb5529594021d8cb16417c8bebdd68a3e136c2",
                 6,
-                "33390d2f614e0f8dd592ab43c2082b018f6067323dbafadafaead1697d5af7ea",
+                "20ddbb76ef88f04e1f0d7399a4ea663cb199f13442d7f3e72e9970715da91c2b",
                 ("target epoch outside window",
                  "target epoch != epoch of slot",
                  "inclusion window",
@@ -355,7 +355,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "get_attesting_indices", _MAINLINE,
-                "f398599283a0c54973da64b80170f90cba0f569250775272f3ad61544c396e69",
+                "dee2168ca0cdf12e5f30c41d8016c7a95a991d67f32fc77c65bfd31845a7d020",
                 0, _NO_RAISES, ()),
         ),
         description="get_attesting_indices over the committee-context "
@@ -369,9 +369,9 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "process_sync_aggregate", ("altair", "bellatrix"),
-                "3015446276968a899111fa2b38c80ec256715f97d6e28dab790ffa6b47b12941",
+                "26307ed96010cd9f29a403c55c628de59c465903c923df3a6a8b6bd1a12f814c",
                 1,
-                "24b1e85472e8e02ef814754e0c867a4b36e08a016bb71273508a302ebb1488a4",
+                "aeca6b7e2e1b347c032001a2bbca15d8afee4c124139d076d8bb74694ae777bc",
                 ("empty sync set, non-infinity sig",)),
         ),
         description="process_sync_aggregate with the committee signature "
@@ -387,31 +387,31 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "get_attestation_deltas", ("phase0",),
-                "57d93e96de568884c1d12d2c659a9ae71ebd6c05a3b23dc25e49c5687af8fb65",
+                "64de15a4cc5e3db1d17277ed3f803343555461da0d653f0cbcc9ed3ef21ede5d",
                 0, _NO_RAISES, ()),
             SpecPin(
                 "get_source_deltas", ("phase0",),
-                "b8094ac90cefc0adac8e1cbb507d6d42fec3637c7b3952954453abd8eab76f02",
+                "5e75607d37f765386a2878a03149312c67dfe05f85e6323ffb3b1ca74b87f71b",
                 0, _NO_RAISES, ()),
             SpecPin(
                 "get_target_deltas", ("phase0",),
-                "4fe3d9df4f3afe0d0a2d82fad4bb31248daf68659c3f195acaff7614acc547b2",
+                "2de4622f2b5d81df3b1b34856dc72c4c4d1a9a581619dc2a7327e053f5db92d1",
                 0, _NO_RAISES, ()),
             SpecPin(
                 "get_head_deltas", ("phase0",),
-                "c9006c88efab4fbff09f44bc0f4611f9bbba3637317f5621866561790c8037ef",
+                "d109c6ddbfba7215de621d9457b7f3077e61fd622dbb64557455ceb880ea05c9",
                 0, _NO_RAISES, ()),
             SpecPin(
                 "get_inclusion_delay_deltas", ("phase0",),
-                "28d5c289e6e0b59d758b90c0e4e5efbe51d133c1d6db45b03544c9e622e29afe",
+                "bfcc93448fd42cb38dc656f52897e3364cd0319b8163e2fa3fc701af9d8f9ad5",
                 0, _NO_RAISES, ()),
             SpecPin(
                 "get_inactivity_penalty_deltas", ("phase0",),
-                "287d5901d992d44e9b63e0d970452fc4941ce115fdd9190cda9c488f95a7434a",
+                "8665ccbf8cba1c2ad5afd51141fa0b9eeebce433b9a0001468c538a784433025",
                 0, _NO_RAISES, ()),
             SpecPin(
                 "get_attestation_component_deltas", ("phase0",),
-                "701ddea8e5d035c671b5210bfebc62eb7d045acef3efb5285ec67b48beb2aeb8",
+                "336b31ea8e7c703ff7cd286b37b31b33a3521b864e549ac9d12ee8e074ef6bba",
                 0, _NO_RAISES, ()),
         ),
         description="get_attestation_deltas and its six component-delta "
@@ -424,17 +424,17 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "get_matching_source_attestations", ("phase0",),
-                "8736a57fbd9c948da87cf9b45e0177c138f3865cd32f9a712f00a93e19856d25",
+                "cb8c7f1fc9651f9b3f389bd4af6a8529799379b6c04de185b431ee315bad7f5f",
                 1,
-                "00f4fbcd27e8cae795685ad19dbb89cfa5f58f162257abafa96bfd48b6728fc6",
+                "3374dc63a73749ad5960a1f6906d73b57c94c558fd64c7abe93989f15efffa31",
                 ("assert int(epoch) in (prev_epoch, cur_epoch)",)),
             SpecPin(
                 "get_matching_target_attestations", ("phase0",),
-                "0b6d84fbc728f366b72347e715d065289f3a1c742eb628a8adcd8a3643b83f84",
+                "dd087c98cffcfd77173fb2ed19f54e8d99d3fe09e5e0fc7c7b04f7e18d1f5dd6",
                 0, _NO_RAISES, ()),
             SpecPin(
                 "get_matching_head_attestations", ("phase0",),
-                "2e25d6be32923bc6afa4d1a5d4a94d83842fa5434b0604cb250dfe13cbb6cc93",
+                "44708ec6eb815a27bdaa4cf35670aafe8f058e515733a5a1a486bf6859e79c91",
                 0, _NO_RAISES, ()),
         ),
         description="the three matching-attestation filters as one cached "
@@ -447,7 +447,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "get_attesting_balance", ("phase0",),
-                "c2398c4b955297eeaa908ef26adfadfaf23fce8288fb989fec702c762e9d20fa",
+                "45316a74c13ee00c5b95f2f64d0312c4fa8cbe13e2f7bb63bf6c36c1a75dd957",
                 0, _NO_RAISES, ()),
         ),
         description="get_attesting_balance summed over the numpy "
@@ -460,11 +460,11 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "get_attesting_indices", _MAINLINE,
-                "f398599283a0c54973da64b80170f90cba0f569250775272f3ad61544c396e69",
+                "dee2168ca0cdf12e5f30c41d8016c7a95a991d67f32fc77c65bfd31845a7d020",
                 0, _NO_RAISES, ()),
             SpecPin(
                 "get_unslashed_attesting_indices", ("phase0",),
-                "83fee5823f4db643118c5ad1d8a4313bca07511cfc8d0ebba85efc15c8298361",
+                "217958bfc2badbaf3315f9b0480f45aec8305b81e1012b95f9b03ca2a7e92f53",
                 0, _NO_RAISES, ()),
         ),
         description="per-attestation attesting sets and their unslashed "
@@ -477,7 +477,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "get_total_active_balance", _ALL,
-                "6a793727c3b425c589cb9ed98f8463cb10910a5e7c347b3bdbe19bc71fc021d9",
+                "ef640e5238ec0462f4c6c6da7d02f34b52e1b92f4b24f22bad4acaf7ac9654a9",
                 0, _NO_RAISES, ()),
         ),
         description="get_total_active_balance as a masked column sum "
@@ -490,7 +490,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "get_active_validator_indices", _ALL,
-                "60c2eb3bf529bfc5704da36216befb8d32f4939a3384768e482965d07754d0b4",
+                "7f0fb8053d6737f8237785d22b188391b522898a9856486f6e48bedc34127fd3",
                 0, _NO_RAISES, ()),
         ),
         description="get_active_validator_indices off the cached "
@@ -503,7 +503,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "process_effective_balance_updates", _ALL,
-                "de498e249b8c2a4d574f873161a7d4185d77a3e86d9d178ca39f97742fff7994",
+                "c7d2ce8328c3bd5a31630119a9cd6f74df9d7334fa4f6c199c8e244c9943ebbe",
                 0, _NO_RAISES, ()),
         ),
         description="process_effective_balance_updates' hysteresis sweep "
@@ -516,7 +516,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "process_registry_updates", _ALL,
-                "61556b40273fe1ad20d5ebc4900213ba0353b3f6571c6b31ffd8ff4c0a6b2183",
+                "7e24127a803379dd79f5287c3301599c98a3c6c45c9d68c8667153d8c58bbc46",
                 0, _NO_RAISES, ()),
         ),
         description="process_registry_updates' eligibility/ejection/"
@@ -529,15 +529,15 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "process_slashings", ("phase0",),
-                "f0be66e6b4d1ba09fb787080365249e3dda1c0988600fb18565dab63cb80b871",
+                "5ca5905ca8c1ce0eee54482da027acb5b99a2a1dd025dbee84d8c7b6eaa00bb8",
                 0, _NO_RAISES, ()),
             SpecPin(
                 "process_slashings", ("altair",),
-                "cdbe9db79fee2e4f9f21f8085cf7a1c733f2aa95f8922ddfc95db0dbcf2e4ebc",
+                "9d9e75d69c36ce784435efadea16ac1f432273e114c0d50e548071d3bdb01323",
                 0, _NO_RAISES, ()),
             SpecPin(
                 "process_slashings", ("bellatrix", "capella"),
-                "e1402b320d51e3c6b5f372c76892ab068efa582e6ba8afc767b1d573be58c093",
+                "03e45ac7b66a9c0716bfeb7a2f5b9dd5c501b03f1ae0db17383c198b4aa50ffb",
                 0, _NO_RAISES, ()),
         ),
         description="process_slashings across all three fork variants, "
@@ -552,7 +552,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "process_justification_and_finalization", _ALTAIR_ON,
-                "e4f557ee474a383770d16f7d35405fccb9ad7ca4f32aaeaa5bfd8262290e5358",
+                "e3f33fe65ff767bcf0f9148b883eb4cd918b4f1f9a794758ad69493e753b7693",
                 0, _NO_RAISES, ()),
         ),
         description="altair+ process_justification_and_finalization off "
@@ -565,19 +565,19 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "process_rewards_and_penalties", _ALTAIR_ON,
-                "f0a9c26ab0c86f48ca872b3871f676965d60c7d44b41490af4541f6b2e5c73a3",
+                "aea1452a760bcb187e5999d9a54e90e0aa75fcd311baaf967174585e7ece7211",
                 0, _NO_RAISES, ()),
             SpecPin(
                 "get_flag_index_deltas", _ALTAIR_ON,
-                "60a1bf4b2054bf97719269fbdf76aa26ed4ffaddc7b18e14fc8d9149d237cfa4",
+                "26142da130a460a244b7e0c1b4dda1bc21eca4722c4dad416f8a64c3b0009411",
                 0, _NO_RAISES, ()),
             SpecPin(
                 "get_inactivity_penalty_deltas", ("altair",),
-                "88fd01e6a6fbdfb8aba9c7050d53fe51f8b76c1e35330933ac2f7595a0826c06",
+                "c5321b40a093a2158442c85c2ef0322f5f6512ec6465c4548fc7c68b19b69641",
                 0, _NO_RAISES, ()),
             SpecPin(
                 "get_inactivity_penalty_deltas", ("bellatrix", "capella"),
-                "af4f67bf011d475f5e9d0a5498b9013e4ec517648dc7549e883bf1b361857631",
+                "9bf4bf09c52fb2f7c1917a0072e3bf522ea9234c0230642be69b765fc3bba1b2",
                 0, _NO_RAISES, ()),
         ),
         description="altair+ process_rewards_and_penalties: flag-index "
@@ -591,7 +591,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "process_inactivity_updates", _ALTAIR_ON,
-                "7ab645178cdfbd8108e67c9f2a29d58cb13addc12ace44f9c1bf52c7b0d09a7a",
+                "b9ffa95fc30a72baece3eaea2e8f39d8aecb854464a43343fb9ee83d0c2a483a",
                 0, _NO_RAISES, ()),
         ),
         description="process_inactivity_updates' score bump/decay "
@@ -604,7 +604,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "process_participation_flag_updates", _ALTAIR_ON,
-                "285079d9731676864386d34360ebbc6ff4c1756bbc3e92420b818019e6d82e51",
+                "d4cffbba85ee8ab702fc975454ab3e73b13341df40f53a51e5cd06191e3b1056",
                 0, _NO_RAISES, ()),
         ),
         description="process_participation_flag_updates' epoch rotation "
@@ -617,9 +617,9 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "get_unslashed_participating_indices", _ALTAIR_ON,
-                "44ef5345826444575dfb8c9f332df0a90d707fe5a84dc8187487fad4a4ee5d96",
+                "2924aecacd9083131d068c53cdceb14b9d57d1d6fb5f264334cd1bdb59ecb70d",
                 1,
-                "00f4fbcd27e8cae795685ad19dbb89cfa5f58f162257abafa96bfd48b6728fc6",
+                "3374dc63a73749ad5960a1f6906d73b57c94c558fd64c7abe93989f15efffa31",
                 (None,)),
         ),
         description="get_unslashed_participating_indices as a boolean "
@@ -635,7 +635,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "compute_committee", _ALL,
-                "fb1ca571347798d66ad297ed49a5dc831187744aec393d0d80df92486b2c9610",
+                "aed4517144e5f175520771da6108e78998707bd44b2b85f52b1d896be9bdfc24",
                 0, _NO_RAISES, ()),
         ),
         description="compute_committee via one whole-permutation shuffle "
@@ -648,7 +648,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "is_valid_indexed_attestation", _ALL,
-                "34cd6f7f83c8d58d310f41243228c4301e418b5469c2f6b2c447fa3bead18568",
+                "b7f57dbe3ee4dbfff347de22107551666d9724025f79100d1627b6bb3ce797ec",
                 0, _NO_RAISES, ()),
         ),
         description="is_valid_indexed_attestation with pubkey gathers off "
@@ -661,9 +661,9 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "process_attestation", _ALTAIR_ON,
-                "f68c9cabb76a1fe7ebff6aef2a13a5677773948f6fe1e017126e00aa8c3047df",
+                "55fbc8605211de2947e1279200fb5529594021d8cb16417c8bebdd68a3e136c2",
                 6,
-                "33390d2f614e0f8dd592ab43c2082b018f6067323dbafadafaead1697d5af7ea",
+                "20ddbb76ef88f04e1f0d7399a4ea663cb199f13442d7f3e72e9970715da91c2b",
                 ('assert data.target.epoch in (',
                  'assert data.target.epoch == g["compute_epoch_at_slot"](data.slot)',
                  'assert (data.slot + g["MIN_ATTESTATION_INCLUSION_DELAY"]',
@@ -682,9 +682,9 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "process_sync_aggregate", _ALTAIR_ON,
-                "3015446276968a899111fa2b38c80ec256715f97d6e28dab790ffa6b47b12941",
+                "26307ed96010cd9f29a403c55c628de59c465903c923df3a6a8b6bd1a12f814c",
                 1,
-                "24b1e85472e8e02ef814754e0c867a4b36e08a016bb71273508a302ebb1488a4",
+                "aeca6b7e2e1b347c032001a2bbca15d8afee4c124139d076d8bb74694ae777bc",
                 ('assert g["eth_fast_aggregate_verify"](',)),
         ),
         description="process_sync_aggregate with index-based reward "
@@ -697,7 +697,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "process_rewards_and_penalties", ("phase0",),
-                "48d5e12795ec2711cb1ddcb4d4d1ffb2ca6cd8a7e885d9a61448ec46b3796902",
+                "01f013e60551d086b7362b8f6765479e231e5ce65ad101d3d071a9299c90b583",
                 0, _NO_RAISES, ()),
         ),
         description="phase0 process_rewards_and_penalties applying the "
@@ -710,7 +710,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "get_attestation_deltas", ("phase0",),
-                "57d93e96de568884c1d12d2c659a9ae71ebd6c05a3b23dc25e49c5687af8fb65",
+                "64de15a4cc5e3db1d17277ed3f803343555461da0d653f0cbcc9ed3ef21ede5d",
                 0, _NO_RAISES, ()),
         ),
         description="get_attestation_deltas adapter returning the "
@@ -724,19 +724,19 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "on_attestation", _MAINLINE,
-                "c3f227c9a0748e9550ab20eea8f9e5d496bc53c53cf14c99713aae26c62f8126",
+                "225e58e81ebd1447772a4cfce7a4080a4999a68485288ae828b5da5fc9c11732",
                 1,
-                "b0c936ed18b0f75174ceabdc4de8ea4abe5cea4ddb2d4612d040cf30f90ba574",
+                "0f0641ad52c78f671502e15e54f96127c427963ae102f4be4264437389239e9e",
                 ("assert spec.is_valid_indexed_attestation(target_state, indexed)",)),
             SpecPin(
                 "validate_on_attestation", _MAINLINE,
-                "5c2f9b16177dfeef9b3c30d690362fb9579c1033c36708b8e7a5d78fd4880d69",
+                "69be41da45a52f99cda054c19ab4641927fb6d3a0fe755f36a778308c33c59fd",
                 6,
-                "96af4d0f89a899b3bb1293f3b8922c86f556f90441158b9365bc648160bd5513",
+                "af0d95f4cea1dd29ff5496794dc0391c64844eb6b93396376aaddc046319286e",
                 (None, None, None, None, None, None)),
             SpecPin(
                 "update_latest_messages", _MAINLINE,
-                "2ef398cdc585f21953aba6721b4c37c4c7ddc137939eb7f3208924f7a39f2f7d",
+                "fbf81b9239c601dcc39e938639bc9bb93d4dbc01ed4d6d7b33c1098720590f00",
                 0, _NO_RAISES, ()),
         ),
         description="batched on_attestation: validate_on_attestation runs "
@@ -753,9 +753,9 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "build_proof", ("ssz",),
-                "6a3f664c07c188140305928ac6ac27701103ebd4f84582524080ce4ee8e92fac",
+                "3665e1668f9393bf7029cd78ba6d020bef133da48d2eaae075643c872df77170",
                 1,
-                "3a322f1fcc38f8f487096428a27b2e9fd6fbee8ae1bba270ed70f5c815eb0360",
+                "78f3304ffd171e60e2430f73928f02e1fc29b312a087104bbd5f1be5ae7b951e",
                 (None,)),
         ),
         description="ssz.gindex.build_proof regenerated off checkpoint "
@@ -769,7 +769,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "is_valid_merkle_branch", _MAINLINE,
-                "2dc105975b7b0c4aca27dceffbb5f4a9e4c4974038cab4d2f8ee94c6271edbaa",
+                "318f9706d9d7af45a351606c4fa891dbe005e86800458ef6ed918b2b0195947d",
                 0, _NO_RAISES, ()),
         ),
         description="is_valid_merkle_branch's fold over a leaf-side-first "

@@ -11,8 +11,9 @@ latest fork in the chain that defines a name wins) and derives, for each
 (fork, function):
 
 * an **AST-normalized digest** — the function is re-parsed, its docstring
-  dropped, and ``ast.dump`` hashed, so comment/whitespace/docstring churn
-  never fires SP01 while any semantic edit does;
+  dropped, and a schema-independent dump (``_canonical``) hashed, so
+  comment/whitespace/docstring churn never fires SP01 while any semantic
+  edit does, on any interpreter;
 * the ordered **raise sites** (``assert``/``raise`` statements) with a
   digest over their normalized conditions — SP03's audit unit;
 * the bare-name **call targets** — spec sources call globals directly, so
@@ -127,23 +128,41 @@ def _strip_docstring(node: ast.FunctionDef) -> ast.FunctionDef:
     return clone
 
 
+def _canonical(node) -> str:
+    """A dump of ``node`` that no interpreter's AST schema changes: fields
+    are named, and fields left None or empty are omitted, so a field that
+    a newer Python adds (3.12's ``type_params``) digests like its absence.
+    ``ast.dump`` lists every field of the running interpreter's schema."""
+    if isinstance(node, ast.AST):
+        parts = []
+        for name in node._fields:
+            value = getattr(node, name, None)
+            if value is None or value == []:
+                continue
+            parts.append(f"{name}={_canonical(value)}")
+        return f"{type(node).__name__}({', '.join(parts)})"
+    if isinstance(node, list):
+        return f"[{', '.join(_canonical(v) for v in node)}]"
+    return repr(node)
+
+
 def _function_facts(node: ast.FunctionDef, fork: str, src: str,
                     lines: List[str]) -> SpecFunction:
-    dump = ast.dump(_strip_docstring(node), annotate_fields=False)
-    digest = hashlib.sha256(dump.encode()).hexdigest()
+    digest = hashlib.sha256(
+        _canonical(_strip_docstring(node)).encode()).hexdigest()
 
     sites: List[RaiseSite] = []
     calls: set = set()
     for sub in ast.walk(node):
         if isinstance(sub, ast.Assert):
-            detail = "assert " + ast.dump(sub.test, annotate_fields=False)
+            detail = "assert " + _canonical(sub.test)
             if sub.msg is not None:
-                detail += ", " + ast.dump(sub.msg, annotate_fields=False)
+                detail += ", " + _canonical(sub.msg)
             sites.append(RaiseSite(sub.lineno, "assert", detail,
                                    _src_line(lines, sub.lineno)))
         elif isinstance(sub, ast.Raise):
             detail = "raise " + (
-                ast.dump(sub.exc, annotate_fields=False) if sub.exc else "")
+                _canonical(sub.exc) if sub.exc else "")
             sites.append(RaiseSite(sub.lineno, "raise", detail,
                                    _src_line(lines, sub.lineno)))
         elif isinstance(sub, ast.Call) and isinstance(sub.func, ast.Name):

@@ -12,7 +12,7 @@ Usage::
     python tools/copycheck.py [--threshold 0.5] [--json COPYCHECK_SELF.json]
 
 Exits non-zero if any non-exempt file exceeds the threshold.  Exemptions are
-declared in EXEMPT with a reason; each must be defensible in COVERAGE.md
+declared in EXEMPT with a reason; each must be defensible on its own
 (e.g. the normative spec transcriptions, which BASELINE mandates byte-identical
 and which the fidelity suite pins AST-for-AST to the vendored markdown).
 """

@@ -128,13 +128,11 @@ def main() -> None:
     except Exception as exc:  # probe resilience: record, don't lose the rest
         report["mxu_error"] = repr(exc)[:300]
 
-    try:
-        # achieved-vs-peak accounting regenerates with every probe run
+    if jax.devices()[0].platform == "tpu":
+        # achieved-vs-peak accounting regenerates with every chip run
         import mfu
 
-        mfu.annotate_limb_probe(report)
-    except Exception as exc:
-        report["mxu_mfu_error"] = repr(exc)[:200]
+        mfu.annotate_limb_probe(report, jax.devices()[0].device_kind)
 
     with open("LIMB_PROBE.json", "w") as f:
         json.dump(report, f, indent=1)
