@@ -41,13 +41,22 @@ _COLS_CACHE = None  # RootKeyedCache(4), built lazily (bulk imports jax-free)
 
 def registry_columns(state):
     """Cached numpy columns of the validator registry, keyed by the
-    registry's tree root (mutation -> new root -> automatic refresh)."""
-    from consensus_specs_tpu.ssz import bulk
-
+    registry's tree root (mutation -> new root -> automatic refresh); a
+    miss walks the registry tree in an ``ops/registry_walk`` span."""
     global _COLS_CACHE
     if _COLS_CACHE is None:
+        from consensus_specs_tpu.ssz import bulk
+
         _COLS_CACHE = bulk.RootKeyedCache(4)
-    return _COLS_CACHE.get(state.validators, bulk.validator_columns)
+    return _COLS_CACHE.get(state.validators, _walk_registry)
+
+
+def _walk_registry(validators):
+    from consensus_specs_tpu import tracing
+    from consensus_specs_tpu.ssz import bulk
+
+    with tracing.span("ops/registry_walk"):
+        return bulk.validator_columns(validators)
 
 
 def active_mask(cols, epoch: int) -> np.ndarray:

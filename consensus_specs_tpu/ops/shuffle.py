@@ -27,7 +27,8 @@ _CACHE_MAX = 16
 
 def compute_shuffle_permutation(seed: bytes, index_count: int, round_count: int) -> np.ndarray:
     """Return an int64 array p of length index_count with
-    p[i] = compute_shuffled_index(i, index_count, seed)."""
+    p[i] = compute_shuffled_index(i, index_count, seed).  A cache miss is
+    one ``ops/shuffle`` span."""
     from consensus_specs_tpu import tracing
 
     key = (bytes(seed), int(index_count), int(round_count))
@@ -35,11 +36,14 @@ def compute_shuffle_permutation(seed: bytes, index_count: int, round_count: int)
     if hit is not None:
         tracing.count("shuffle.permutation_cache_hit")
         return hit
-    tracing.count("shuffle.permutation_compute")
-    n = int(index_count)
+    with tracing.span("ops/shuffle"):
+        return _compute(key)
+
+
+def _compute(key: Tuple[bytes, int, int]) -> np.ndarray:
+    seed, n, round_count = key
     if n == 0:
         return np.zeros(0, dtype=np.int64)
-    seed = bytes(seed)
     m = np.arange(n, dtype=np.int64)
     n_blocks = (n + 255) // 256
     block_ids = np.arange(n_blocks, dtype=np.int64)

@@ -29,7 +29,7 @@ from typing import List, NamedTuple
 
 import numpy as np
 
-from consensus_specs_tpu import faults, telemetry
+from consensus_specs_tpu import faults, telemetry, tracing
 from consensus_specs_tpu.ops.segment import segment_sum
 from consensus_specs_tpu.ops.shuffle import committee_bounds, compute_shuffle_permutation
 from consensus_specs_tpu.ssz import bulk
@@ -150,18 +150,21 @@ def committee_context(spec, state, epoch: int) -> _CommitteeContext:
     root + attester seed (the full input set of the spec's committee
     computation); a lookup layer keyed on the memoized registry/randao
     roots makes the per-attestation hit path a dict probe instead of a
-    ``get_seed`` hash chain."""
+    ``get_seed`` hash chain.  A lookup miss is the ``stf/committees``
+    span: seed, and on a context miss the active set, permutation and
+    bounds."""
     lookup_key = _ctx_lookup_key(spec, state, epoch)
     ctx = _CTX_LOOKUP.get(lookup_key)
     if ctx is not None:
         return ctx
-    seed = bytes(spec.get_seed(
-        state, spec.Epoch(epoch), spec.DOMAIN_BEACON_ATTESTER))
-    key = (lookup_key[0], int(epoch), seed, _spec_geometry_key(spec))
-    ctx = _CTX_CACHE.get(key)
-    if ctx is None:
-        ctx = _fifo_put(
-            _CTX_CACHE, key, _CommitteeContext(spec, state, int(epoch), seed))
+    with tracing.span("stf/committees"):
+        seed = bytes(spec.get_seed(
+            state, spec.Epoch(epoch), spec.DOMAIN_BEACON_ATTESTER))
+        key = (lookup_key[0], int(epoch), seed, _spec_geometry_key(spec))
+        ctx = _CTX_CACHE.get(key)
+        if ctx is None:
+            ctx = _fifo_put(
+                _CTX_CACHE, key, _CommitteeContext(spec, state, int(epoch), seed))
     return _fifo_put(_CTX_LOOKUP, lookup_key, ctx)
 
 

@@ -44,7 +44,7 @@ from typing import Dict
 
 import numpy as np
 
-from consensus_specs_tpu import telemetry
+from consensus_specs_tpu import telemetry, tracing
 
 from . import staging
 
@@ -183,7 +183,8 @@ def balance_column(state) -> np.ndarray:
             stats["balance_hits"] += 1
             return hit
     stats["balance_misses"] += 1
-    col = bulk.packed_uint64_to_numpy(view)
+    with tracing.span("stf/balance_walk"):
+        col = bulk.packed_uint64_to_numpy(view)
     col.setflags(write=False)
     if root is not None:
         _bounded_put(_BALANCE_STORE, _BALANCE_STORE_MAX, bytes(root), col)

@@ -187,7 +187,6 @@ def _breaker_note_error() -> None:
         _breaker["since_skipped"] = 0
         stats["breaker_trips"] += 1
         stats["breaker_state"] = "open"
-        tracing.count("stf.breaker_tripped")
         recorder.record("breaker_open",
                         consecutive_errors=_breaker["consecutive_errors"])
 
@@ -199,7 +198,6 @@ def _breaker_allows_attempt() -> bool:
     _breaker["since_skipped"] += 1
     if _breaker["since_skipped"] % BREAKER_PROBE_INTERVAL == 0:
         stats["breaker_probes"] += 1
-        tracing.count("stf.breaker_probe")
         recorder.record("breaker_probe")
         return True
     return False
@@ -230,7 +228,6 @@ def _replay_breaker_open(spec, state, signed_block, validate_result: bool,
     stats["replayed_blocks"] += 1
     stats["breaker_skipped"] += 1
     _count_reason("breaker_open")
-    tracing.count("stf.replayed_block")
     if rec:
         recorder.record("block_replayed",
                         slot=int(signed_block.message.slot),
@@ -263,7 +260,6 @@ def _apply_one(spec, state, signed_block, validate_result: bool) -> None:
             _SITE_CACHE_COMMIT()
         stats["fast_blocks"] += 1
         _breaker_note_success()
-        tracing.count("stf.fast_block")
         if rec:
             # after the transaction settled (OB01 discipline: a rolled
             # back block must never log a fast application)
@@ -347,7 +343,7 @@ def _collect_block(spec, state, signed_block, validate_result: bool,
     block = signed_block.message
     altair_lineage = spec.fork != "phase0"
     t0 = time.perf_counter()
-    with timeline.span("host/slot_roots", link=link, slot=int(block.slot)):
+    with tracing.span("host/slot_roots", link=link, slot=int(block.slot)):
         slot_roots.process_slots(spec, state, block.slot)
     t1 = time.perf_counter()
     stats["slot_roots_s"] += t1 - t0
@@ -374,7 +370,7 @@ def _collect_block(spec, state, signed_block, validate_result: bool,
     # altair.py:405-410, bellatrix.py:242-249): header/RANDAO/attestations/
     # sync aggregate run the vectorized or collect-don't-verify variants
     # below; the remaining operations are the spec's own functions
-    with timeline.span("host/operations", link=link):
+    with tracing.span("host/operations", link=link):
         _header(spec, state, block)
         if spec.fork == "bellatrix" and spec.is_execution_enabled(state, block.body):
             # [New in Bellatrix] — literal, inside the snapshot-protected
@@ -392,7 +388,7 @@ def _collect_block(spec, state, signed_block, validate_result: bool,
         t4 = time.perf_counter()
     non_attestation_ops = (t4 - t3) - (stats["attestation_apply_s"] - apply_before)
     if altair_lineage:
-        with timeline.span("host/sync_aggregate", link=link):
+        with tracing.span("host/sync_aggregate", link=link):
             sync.process_sync_aggregate(
                 spec, state, block.body.sync_aggregate, collect, bls_on)
     t4s = time.perf_counter()
@@ -416,7 +412,7 @@ def _fast_transition(spec, state, signed_block, validate_result: bool,
     t5 = time.perf_counter()
     histogram.observe("sig_verify", t5 - t4s)
     if validate_result:
-        with timeline.span("host/state_root", link=link):
+        with tracing.span("host/state_root", link=link):
             computed = _SITE_STATE_ROOT(
                 bytes(slot_roots.state_root(spec, state)))
         if bytes(signed_block.message.state_root) != computed:
@@ -475,7 +471,7 @@ def _begin_block(spec, state, signed_block, validate_result: bool,
         t5 = time.perf_counter()
         stats["sig_verify_s"] += t5 - t4s
         if validate_result:
-            with timeline.span("host/state_root", link=link):
+            with tracing.span("host/state_root", link=link):
                 computed = _SITE_STATE_ROOT(
                     bytes(slot_roots.state_root(spec, state)))
             if bytes(signed_block.message.state_root) != computed:
@@ -507,7 +503,7 @@ def _finish_speculation(pend: _Speculation, rec: bool):
     (LIFO), because blocks may already be speculated on top."""
     a0 = pipeline.stats["await_s"]
     try:
-        with timeline.span("host/await_verdict", link=pend.link):
+        with tracing.span("host/await_verdict", link=pend.link):
             bad = (pipeline.wait(pend.handle)
                    if pend.handle is not None else None)
     except Exception as exc:
@@ -537,7 +533,6 @@ def _finish_speculation(pend: _Speculation, rec: bool):
         return exc
     stats["fast_blocks"] += 1
     _breaker_note_success()
-    tracing.count("stf.fast_block")
     timeline.instant("commit", link=pend.link, slot=pend.slot)
     if rec and pend.rec_delta is not None:
         recorder.record("block_fast", slot=pend.slot, **pend.rec_delta)
@@ -551,7 +546,6 @@ def _account_failure(exc: BaseException) -> None:
         _breaker_note_error()
     _count_reason(type(exc).__name__)
     stats["replayed_blocks"] += 1
-    tracing.count("stf.replayed_block")
 
 
 def _replay_literal(spec, state, signed_block, validate_result: bool,

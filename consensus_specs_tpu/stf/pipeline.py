@@ -50,7 +50,6 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import List, Optional, Sequence
 
 from consensus_specs_tpu import faults, telemetry, tracing
-from consensus_specs_tpu.telemetry import timeline
 
 from . import verify
 
@@ -135,12 +134,11 @@ class SigBatchHandle:
         # the worker's span carries the dispatching block's link, so the
         # Chrome-trace export draws the cross-thread edge host phases →
         # native verify (the PR 10 overlap made visible)
-        sid = timeline.begin("native/verify", link=self.link,
-                             entries=len(self.entries))
         try:
-            return verify.first_invalid(self.entries)
+            with tracing.span("native/verify", link=self.link,
+                              entries=len(self.entries)):
+                return verify.first_invalid(self.entries)
         finally:
-            timeline.end(sid)
             span[1] = time.perf_counter()
 
 
@@ -150,9 +148,10 @@ def dispatch(entries: Sequence[verify.SigEntry],
     be fully materialized (affine buffers built) — the worker touches
     pure data plus the native call, never the geometry caches.  The
     sig-batch tracing counts land HERE (host side; ``verify.settle``
-    emits them on the serial path), keeping the worker tracing-free and
-    the counters alive pipeline ON or OFF.  ``link`` is the dispatching
-    block's timeline causality id (None with the timeline off)."""
+    emits them on the serial path), so they stay alive pipeline ON or
+    OFF; the worker's own instrumentation is its ``native/verify`` span.
+    ``link`` is the dispatching block's timeline causality id (None with
+    the timeline off)."""
     _SITE_DISPATCH()
     tracing.count("stf.sig_batch")
     tracing.count("stf.sig_batch.entries", len(entries))

@@ -32,9 +32,12 @@ _SITE_PROCESS = faults.site("stf.slot_roots.process")
 
 def state_root(spec, state):
     """``hash_tree_root(state)``, with dirty bulk-written balance subtrees
-    routed through the device-resident reduction when the policy engages."""
-    _maybe_resident_balances_root(state)
-    return spec.hash_tree_root(state)
+    routed through the device-resident reduction when the policy engages
+    (the ``stf/state_root`` span; the reduction its ``ssz/resident_root``
+    span: column read, upload, device reduction and readback)."""
+    with tracing.span("stf/state_root"):
+        _maybe_resident_balances_root(state)
+        return spec.hash_tree_root(state)
 
 
 def _maybe_resident_balances_root(state) -> None:
@@ -51,30 +54,32 @@ def _maybe_resident_balances_root(state) -> None:
         return
     from . import columns
 
-    # a device error raises: the block engine rolls the block back and
-    # replays it literally (counted in stf.stats["replayed_blocks"])
-    resident = merkle_resident.ResidentPackedU64List(
-        type(balances).LENGTH, device=device)
-    # resident-column read (ISSUE 10): after the epoch transition's flush
-    # this is the identity fast path — no tree walk before the device upload
-    resident.upload(columns.balance_column(state).astype("u8"))
-    merkle_resident.memoize_packed_u64_contents_root(
-        balances, resident.contents_subtree_root())
+    with tracing.span("ssz/resident_root"):
+        # a device error raises: the block engine rolls the block back and
+        # replays it literally (counted in stf.stats["replayed_blocks"])
+        resident = merkle_resident.ResidentPackedU64List(
+            type(balances).LENGTH, device=device)
+        # resident-column read: after the epoch transition's flush this is
+        # the identity fast path — no tree walk before the device upload
+        resident.upload(columns.balance_column(state).astype("u8"))
+        merkle_resident.memoize_packed_u64_contents_root(
+            balances, resident.contents_subtree_root())
     tracing.count("stf.resident_slot_root")
 
 
 def process_slots(spec, state, slot) -> None:
     """Spec-identical ``process_slots`` (same asserts, same mutations, the
     spec module's own ``process_epoch``) with per-slot roots through
-    ``state_root`` above."""
+    ``state_root`` above; one ``stf/process_slots`` span per call."""
     assert state.slot < slot
-    while state.slot < slot:
-        _SITE_PROCESS()
-        _process_slot(spec, state)
-        # Process epoch on the start slot of the next epoch
-        if (state.slot + 1) % spec.SLOTS_PER_EPOCH == 0:
-            spec.process_epoch(state)
-        state.slot = spec.Slot(state.slot + 1)
+    with tracing.span("stf/process_slots"):
+        while state.slot < slot:
+            _SITE_PROCESS()
+            _process_slot(spec, state)
+            # Process epoch on the start slot of the next epoch
+            if (state.slot + 1) % spec.SLOTS_PER_EPOCH == 0:
+                spec.process_epoch(state)
+            state.slot = spec.Slot(state.slot + 1)
 
 
 def _process_slot(spec, state) -> None:

@@ -30,7 +30,7 @@ import hashlib
 from typing import List, Optional, Sequence, Tuple
 
 from consensus_specs_tpu import faults, telemetry, tracing
-from consensus_specs_tpu.telemetry import recorder, timeline
+from consensus_specs_tpu.telemetry import recorder
 
 from . import staging
 
@@ -254,7 +254,7 @@ def settle(entries: List[SigEntry], keys: List[bytes],
         return None
     tracing.count("stf.sig_batch")
     tracing.count("stf.sig_batch.entries", len(entries))
-    with timeline.span("native/verify", link=link, entries=len(entries)):
+    with tracing.span("native/verify", link=link, entries=len(entries)):
         bad = first_invalid(entries, seed=seed)
     if bad is not None:
         return bad

@@ -22,7 +22,11 @@ same report shape, two hardening changes the facade alone could not make:
   the fresh functions.
 
 Disabled (the default), ``span``/``count`` cost one module-global load
-and a truth check — nothing to measure in a phase breakdown.
+and a truth check — nothing to measure in a phase breakdown.  Enabled,
+every span also runs inside ``jax.profiler.TraceAnnotation("cstpu." +
+name)``, so it lands on the device trace's clock whenever a profiler
+trace is being taken; the class is looked up at ``enable()``, never at
+import, so importing telemetry does not import ``jax``.
 """
 from __future__ import annotations
 
@@ -38,10 +42,16 @@ _LOCK = threading.Lock()
 _spans: Dict[str, list] = {}  # name -> [count, total_s]
 _counters: Dict[str, int] = {}
 _tls = threading.local()  # per-thread span nesting stack
+_annotation = None  # jax.profiler.TraceAnnotation, looked up by enable()
 
 
 def enable() -> None:
-    global _enabled
+    global _enabled, _annotation
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:  # no jax: spans reach the report and timeline only
+        TraceAnnotation = None
+    _annotation = TraceAnnotation
     _enabled = True
 
 
@@ -69,19 +79,22 @@ def _stack() -> list:
 
 
 @contextlib.contextmanager
-def span(name: str):
+def span(name: str, link=None, **fields):
     """Nested wall-time span; keys are '/'-joined paths.  Nesting is
     per-thread: concurrent spans from different threads each build their
     own path, and the aggregate mutation is lock-guarded.
 
     Every span site doubles as a causal-timeline emitter (ISSUE 11):
     with ``CSTPU_TIMELINE`` armed, the same begin/end lands as paired
-    timeline events — existing ``tracing.span`` callsites feed the
-    Chrome-trace export without touching a line of producer code.  The
-    nesting stack builds the same '/'-joined key either way, so a span's
-    exported name is identical whether the metrics layer is on or the
-    timeline alone is.  Both layers disabled, the cost stays two
-    module-global loads and a truth check."""
+    timeline events carrying ``link`` (the causality id) and ``fields`` —
+    existing ``tracing.span`` callsites feed the Chrome-trace export
+    without touching a line of producer code.  The nesting stack builds
+    the same '/'-joined key either way, so a span's exported name is
+    identical whether the metrics layer is on or the timeline alone is.
+    With the metrics layer on, the span is also a profiler annotation
+    ``cstpu.<name>`` (its own name: the profiler nests annotations
+    itself).  Both layers disabled, the cost stays two module-global
+    loads and a truth check."""
     tl = timeline.enabled()
     if not _enabled and not tl:
         yield
@@ -89,7 +102,10 @@ def span(name: str):
     stack = _stack()
     stack.append(name)
     key = "/".join(stack)
-    sid = timeline.begin(key) if tl else 0
+    sid = timeline.begin(key, link=link, **fields) if tl else 0
+    annotation = _annotation("cstpu." + name) if _enabled and _annotation else None
+    if annotation is not None:
+        annotation.__enter__()
     t0 = time.perf_counter() if _enabled else 0.0
     try:
         yield
@@ -102,6 +118,8 @@ def span(name: str):
                     rec = _spans[key] = [0, 0.0]
                 rec[0] += 1
                 rec[1] += dt
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
         timeline.end(sid)
         stack.pop()
 
@@ -122,15 +140,6 @@ def report() -> dict:
             },
             "counters": dict(sorted(_counters.items())),
         }
-
-
-@contextlib.contextmanager
-def xla_trace(log_dir: str):
-    """Device-level XLA profiler trace (TensorBoard/XProf format)."""
-    import jax
-
-    with jax.profiler.trace(log_dir):
-        yield
 
 
 # --- spec instrumentation ----------------------------------------------------

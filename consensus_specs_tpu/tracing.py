@@ -16,8 +16,10 @@ using, byte-compatible:
   spec rebuilds (identity-marked wrappers, not copyable flags).
 * **Counters** — monotonically increasing named counters
   (``count(name)``), e.g. BLS verifications, cache hits.
-* **XLA profiler** — ``xla_trace(dir)`` wraps ``jax.profiler.trace`` for
-  device-level traces viewable in TensorBoard/XProf.
+* **Profiler clock** — while enabled, every span is also a
+  ``jax.profiler.TraceAnnotation`` named ``cstpu.<name>``, so a
+  ``jax.profiler`` trace taken meanwhile shows the program's spans beside
+  the device's operations.
 
 Snapshot everything with ``report()``; reset with ``reset()``.  The
 report also rides the telemetry bus as the ``"tracing"`` provider
@@ -35,10 +37,9 @@ from consensus_specs_tpu.telemetry.metrics import (  # noqa: F401
     report,
     reset,
     span,
-    xla_trace,
 )
 
 __all__ = [
     "count", "disable", "enable", "enabled", "instrument_spec", "report",
-    "reset", "span", "xla_trace",
+    "reset", "span",
 ]
