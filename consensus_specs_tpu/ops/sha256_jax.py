@@ -105,6 +105,16 @@ def sha256_block64(blocks: jnp.ndarray) -> jnp.ndarray:
     return jnp.stack(out, axis=1)
 
 
+def sha256_single_block(blocks: jnp.ndarray) -> jnp.ndarray:
+    """SHA-256 of N messages of at most 55 bytes, each already padded into
+    one 64-byte block (message, ``0x80``, zeros, 64-bit big-endian bit
+    length), given as [N, 16] big-endian uint32.  One compression per
+    lane.  Returns [N, 8] uint32 digests."""
+    n = blocks.shape[0]
+    init = tuple(jnp.full((n,), _H0[i], dtype=jnp.uint32) for i in range(8))
+    return jnp.stack(_compress(init, blocks), axis=1)
+
+
 # jax.jit caches one executable per input shape on this single callable
 _jit_block64 = jax.jit(sha256_block64)
 

@@ -125,3 +125,15 @@ def test_balances_wave_schedule_compiles(one_chip, monkeypatch):
     idx = tuple(_shape(one_chip, (s,), jnp.int32) for s in sizes)
     sha256_jax._jit_run_waves.lower(
         _shape(one_chip, known, jnp.uint32), idx, idx).compile()
+
+
+def test_shuffle_permutation_program_compiles(one_chip):
+    import jax.numpy as jnp
+
+    from consensus_specs_tpu.ops import shuffle
+
+    lanes = shuffle.lanes_for(N_VALIDATORS)
+    compiled = shuffle._jit_shuffle.lower(
+        _shape(one_chip, (8,), jnp.uint32), _shape(one_chip, (90,), jnp.int32),
+        _shape(one_chip, (), jnp.int32), lanes=lanes).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30

@@ -2,6 +2,8 @@
 import hashlib
 import random
 
+import pytest
+
 from consensus_specs_tpu.ops import sha256_jax
 
 
@@ -86,3 +88,27 @@ def test_wave_path_used_for_large_trees_same_roots():
 
     assert root_jax == root_hashlib
     assert dirty_jax == dirty_hashlib
+
+
+def _single_block_words(messages):
+    """Pad each message of at most 55 bytes into one 64-byte block:
+    0x80, zeros, then the 64-bit big-endian bit length."""
+    import numpy as np
+
+    padded = b"".join(m + b"\x80" + bytes(55 - len(m)) + (8 * len(m)).to_bytes(8, "big")
+                      for m in messages)
+    return np.frombuffer(padded, dtype=">u4").reshape(len(messages), 16).astype(np.uint32)
+
+
+@pytest.mark.parametrize("length, lanes", [(33, 1), (33, 300), (37, 7), (37, 1792)])
+def test_single_block_matches_hashlib(length, lanes):
+    """The single-block entry the shuffle's source hashes use, for its
+    33-byte (seed ‖ round) and 37-byte (seed ‖ round ‖ block) messages,
+    at lane counts that are not powers of two."""
+    import jax
+    import numpy as np
+
+    rng = random.Random(length * 1000 + lanes)
+    messages = [bytes(rng.randrange(256) for _ in range(length)) for _ in range(lanes)]
+    out = np.asarray(jax.jit(sha256_jax.sha256_single_block)(_single_block_words(messages)))
+    assert out.astype(">u4").tobytes() == b"".join(hashlib.sha256(m).digest() for m in messages)
