@@ -36,19 +36,45 @@ _jaxcache.configure()
 # comparison against FAR_FUTURE therefore tests >= _SAT
 _SAT = 2**63 - 1
 
-_COLS_CACHE = None  # RootKeyedCache(4), built lazily (bulk imports jax-free)
+# RootKeyedCache, built lazily (bulk imports jax-free).  Capella blocks with
+# BLS-to-execution changes register a registry version per block
+# (columns_after_writes, which shares every unchanged column), so the cache
+# holds an epoch of them beside the epoch's own versions.
+_COLS_CACHE = None
+_COLS_CACHE_SIZE = 40
 
 
 def registry_columns(state):
     """Cached numpy columns of the validator registry, keyed by the
     registry's tree root (mutation -> new root -> automatic refresh); a
     miss walks the registry tree in an ``ops/registry_walk`` span."""
+    return _cols_cache().get(state.validators, _walk_registry)
+
+
+def _cols_cache():
     global _COLS_CACHE
     if _COLS_CACHE is None:
         from consensus_specs_tpu.ssz import bulk
 
-        _COLS_CACHE = bulk.RootKeyedCache(4)
-    return _COLS_CACHE.get(state.validators, _walk_registry)
+        _COLS_CACHE = bulk.RootKeyedCache(_COLS_CACHE_SIZE)
+    return _COLS_CACHE
+
+
+def columns_after_writes(state, cols: dict, **writes) -> None:
+    """Register the columns of the registry as it stands, derived from
+    ``cols`` (the columns of the registry before the writes) and
+    ``writes`` (``name=(indices, values)``), which must be every registry
+    write since: the next reader gets them without a walk.  A block's
+    insert rides its cache transaction, like every fast-path memo."""
+    from consensus_specs_tpu.stf import staging
+
+    derived = dict(cols)
+    for name, (indices, values) in writes.items():
+        column = cols[name].copy()
+        column[indices] = values
+        derived[name] = column
+    _cols_cache().put(bytes(state.validators.hash_tree_root()), derived,
+                      on_insert=staging.note_insert)
 
 
 def _walk_registry(validators):

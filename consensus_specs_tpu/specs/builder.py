@@ -341,6 +341,8 @@ def _install_optimizations(g: Dict[str, Any]) -> None:
             # clean there and its flush is a no-op)
             _install_altair_attestation_kernel(g)
         _install_sync_aggregate_index(g)  # every fork inherits altair's
+    if "process_full_withdrawals" in g:
+        _install_full_withdrawals_sweep(g)
     _install_deferred_block_verification(g)
 
 
@@ -634,6 +636,18 @@ def _install_altair_epoch_kernel(g: Dict[str, Any]) -> None:
           lambda state: epoch_altair.inactivity_updates(proxy, state))
     _swap(g, "process_participation_flag_updates",
           lambda state: epoch_altair.participation_flag_updates(proxy, state))
+
+
+def _install_full_withdrawals_sweep(g: Dict[str, Any]) -> None:
+    """capella's process_full_withdrawals (the early withdrawals-queue
+    draft) as the column sweep of ops/withdrawals.py: one mask over the
+    cached registry columns instead of a view walk over every validator.
+    Differential: tests/test_full_withdrawals.py."""
+    from consensus_specs_tpu.ops import withdrawals
+
+    proxy = _LiveSpecProxy(g)
+    _swap(g, "process_full_withdrawals",
+          lambda state: withdrawals.full_withdrawals(proxy, state))
 
 
 def _swap(g: Dict[str, Any], name: str, fn) -> None:

@@ -19,12 +19,14 @@ import numpy as np
 
 from .node import (
     BranchNode,
+    LeafNode,
     Node,
     PackedLazySubtree,
     ZERO_HASHES,
     pack_chunks,
     subtree_fill_to_contents,
     uint_to_leaf,
+    with_updated_subtrees,
     zero_node,
 )
 from .types import _collect_leaf_roots
@@ -101,6 +103,27 @@ def set_packed_uint64_from_numpy(view, arr: np.ndarray) -> None:
     _set_packed_from_numpy(view, np.ascontiguousarray(arr, dtype="<u8"))
 
 
+def set_packed_uint64_at(view, arr: np.ndarray, indices) -> None:
+    """Write the chunks of a packed uint64 List/Vector that hold
+    ``indices``, from ``arr`` (the view's whole new contents, equal to the
+    old outside those chunks): one path copy per touched chunk, the rest of
+    the tree and its memoized roots kept.  For a few writes into a large
+    column, where a whole rebuild would cost a node per chunk."""
+    cls = type(view)
+    arr = np.ascontiguousarray(arr, dtype="<u8")
+    if len(arr) != len(view):
+        raise ValueError(f"{len(arr)} values for a view of {len(view)}")
+    updates = [(c, LeafNode(arr[4 * c:4 * c + 4].tobytes().ljust(32, b"\x00")))
+               for c in np.unique(np.asarray(indices, dtype=np.int64) // 4).tolist()]
+    node = view.get_backing()  # flush pending writes
+    contents = with_updated_subtrees(node.left if cls.IS_LIST else node,
+                                     cls.contents_depth(), updates)
+    view._values = None
+    view._dirty_chunks = set()
+    view._backing = BranchNode(contents, node.right) if cls.IS_LIST else contents
+    view._invalidate()
+
+
 def packed_uint8_to_numpy(view) -> np.ndarray:
     """List/Vector[uint8, N] (e.g. altair participation flags) -> uint8."""
     return _packed_to_numpy(view, 1, np.uint8).copy()
@@ -149,6 +172,16 @@ def composite_subtrees(view) -> list:
     return out
 
 
+def composite_list_tail(view, start: int):
+    """A new view of ``view``'s List type holding its elements from
+    ``start`` on: their backing subtrees refilled under a new length, no
+    element rebuilt (the queue dequeue ``queue = queue[start:]``)."""
+    cls = type(view)
+    nodes = composite_subtrees(view)[start:]
+    contents = subtree_fill_to_contents(nodes, cls.contents_depth())
+    return cls.view_from_backing(BranchNode(contents, uint_to_leaf(len(nodes))))
+
+
 def _field_path(field_index: int, depth: int):
     """Descent path (True=right) for a field at the given container depth."""
     return [bool((field_index >> (depth - 1 - b)) & 1) for b in range(depth)]
@@ -173,6 +206,10 @@ _V_FIELDS_U64 = (
     "withdrawable_epoch",
 )
 
+# the full-withdrawal sweep's inputs, on layouts that carry one (capella's
+# early draft): this epoch column, and the credentials' first byte
+_SWEEP_EPOCH = "fully_withdrawn_epoch"
+
 
 def validator_pubkeys(validators) -> list:
     """One walk over the registry subtrees -> every validator's pubkey as
@@ -191,40 +228,85 @@ def validator_pubkeys(validators) -> list:
 
 
 class RootKeyedCache:
-    """FIFO cache keyed by a view's tree root: any mutation produces a new
+    """LRU cache keyed by a view's tree root: any mutation produces a new
     root, so invalidation is automatic.  THE shared memoizer for derived
     registry representations (pubkey column here, numeric columns in
-    ops/epoch_jax.registry_columns)."""
+    ops/epoch_jax.registry_columns).  ``key`` replaces the root as the
+    key (``registry_key`` for values that read no withdrawal field)."""
 
-    def __init__(self, capacity: int):
+    def __init__(self, capacity: int, key=None):
         self.capacity = capacity
+        self.key = key
         self._store: Dict[bytes, object] = {}
 
     def get(self, view, build, on_insert=None):
         """Cached value for ``view``; on a miss, builds and inserts.
         ``on_insert(store, root)`` fires after a fresh insert — the stf
         cache transaction uses it to record the insert for rollback."""
-        root = bytes(view.hash_tree_root())
-        hit = self._store.get(root)
+        root = (self.key or _root_of)(view)
+        hit = self._store.pop(root, None)
         if hit is None:
-            if len(self._store) >= self.capacity:
-                self._store.pop(next(iter(self._store)))
-            hit = build(view)
-            self._store[root] = hit
-            if on_insert is not None:
-                on_insert(self._store, root)
+            return self.put(root, build(view), on_insert)
+        self._store[root] = hit  # most recent last
         return hit
+
+    def put(self, root: bytes, value, on_insert=None):
+        """Insert ``value`` under ``root`` as the most recent entry (the
+        least recent evicted when the cache is full)."""
+        self._store.pop(root, None)
+        if len(self._store) >= self.capacity:
+            self._store.pop(next(iter(self._store)))
+        self._store[root] = value
+        if on_insert is not None:
+            on_insert(self._store, root)
+        return value
+
+
+def _root_of(view) -> bytes:
+    return bytes(view.hash_tree_root())
+
+
+# registry root -> the key of a registry that differs from it only in the
+# withdrawal fields (withdrawal_credentials, fully_withdrawn_epoch).  A
+# capella block rewrites credentials (BLS-to-execution changes) and the
+# epoch sweep sets fully_withdrawn_epoch: neither moves a pubkey, a status
+# epoch or an effective balance, so what those feed (active sets,
+# committees, proposers, pubkey and affine columns, sync seats) carries
+# over instead of being rebuilt for a registry root seen once.  Each entry
+# is a fact about two roots, so an entry recorded by a block that then
+# rolled back is still true.
+_REGISTRY_ALIASES: Dict[bytes, bytes] = {}
+_REGISTRY_ALIASES_MAX = 64
+
+
+def registry_key(validators) -> bytes:
+    """The registry's identity for values that read no withdrawal field:
+    its tree root, or the key of the registry it was derived from by
+    withdrawal-field writes (``note_withdrawal_writes``)."""
+    root = bytes(validators.hash_tree_root())
+    return _REGISTRY_ALIASES.get(root, root)
+
+
+def note_withdrawal_writes(key: bytes, validators) -> None:
+    """Record that ``validators`` differs from the registry keyed ``key``
+    only in withdrawal fields."""
+    root = bytes(validators.hash_tree_root())
+    if root == key or root in _REGISTRY_ALIASES:
+        return
+    if len(_REGISTRY_ALIASES) >= _REGISTRY_ALIASES_MAX:
+        _REGISTRY_ALIASES.pop(next(iter(_REGISTRY_ALIASES)))
+    _REGISTRY_ALIASES[root] = key
 
 
 # 2 entries cover the pre/post-epoch registries a transition touches
-_PUBKEY_CACHE = RootKeyedCache(2)
+_PUBKEY_CACHE = RootKeyedCache(2, key=registry_key)
 
 
 def cached_validator_pubkeys(validators) -> list:
     return _PUBKEY_CACHE.get(validators, validator_pubkeys)
 
 
-_PUBKEY_INDEX_CACHE = RootKeyedCache(2)
+_PUBKEY_INDEX_CACHE = RootKeyedCache(2, key=registry_key)
 
 
 def cached_pubkey_index(validators) -> Dict[bytes, int]:
@@ -242,29 +324,47 @@ def cached_pubkey_index(validators) -> Dict[bytes, int]:
 
 
 def validator_columns(validators) -> Dict[str, np.ndarray]:
-    """One walk over the registry subtrees -> all epoch-processing columns.
+    """One walk over the registry subtrees -> all epoch-processing columns:
+    the uint64 fields of ``_V_FIELDS_U64`` and ``slashed``, and on a layout
+    with the withdrawal sweep also ``fully_withdrawn_epoch`` and
+    ``withdrawal_prefix`` (uint8, the credentials' first byte).
 
     Field paths come from the element class's own layout (field count sets
-    the tree depth).  Saturates epochs at int64 max (FAR_FUTURE_EPOCH =
+    the tree depth); each shared path prefix is descended once for the
+    whole registry.  Saturates epochs at int64 max (FAR_FUTURE_EPOCH =
     2^64-1 would wrap)."""
     et = type(validators).ELEM_TYPE
     depth = et._depth
     findex = et._field_index
-    subs = composite_subtrees(validators)
-    n = len(subs)
+    names = _V_FIELDS_U64 + ((_SWEEP_EPOCH,) if _SWEEP_EPOCH in findex else ())
+    leaves = {name: _field_path(findex[name], depth)
+              for name in names + ("slashed",)}
+    if _SWEEP_EPOCH in findex:
+        leaves["withdrawal_prefix"] = _field_path(
+            findex["withdrawal_credentials"], depth)
+    chunks = _leaf_chunks(composite_subtrees(validators), leaves)
     cols: Dict[str, np.ndarray] = {}
-    u64_paths = {
-        name: _field_path(findex[name], depth) for name in _V_FIELDS_U64
-    }
-    slashed_path = _field_path(findex["slashed"], depth)
-    raw = {name: bytearray() for name in u64_paths}
-    slashed = np.zeros(n, dtype=bool)
-    for i, sub in enumerate(subs):
-        for name, path in u64_paths.items():
-            raw[name] += _walk(sub, path)._root[:8]
-        slashed[i] = _walk(sub, slashed_path)._root[0] != 0
-    for name, buf in raw.items():
-        u = np.frombuffer(bytes(buf), dtype="<u8")
+    for name in names:
+        u = np.ascontiguousarray(chunks[name][:, :8]).view("<u8").ravel()
         cols[name] = np.minimum(u, np.uint64(2**63 - 1)).astype(np.int64)
-    cols["slashed"] = slashed
+    cols["slashed"] = chunks["slashed"][:, 0] != 0
+    if "withdrawal_prefix" in chunks:
+        cols["withdrawal_prefix"] = chunks["withdrawal_prefix"][:, 0].copy()
     return cols
+
+
+def _leaf_chunks(subs: list, paths: Dict[str, list]) -> Dict[str, np.ndarray]:
+    """name -> (n, 32) uint8: the leaf chunk at ``paths[name]`` under each
+    subtree, with every shared path prefix descended once."""
+    level = {(): subs}
+    out = {}
+    for name, path in paths.items():
+        key: tuple = ()
+        for go_right in path:
+            parent, key = level[key], key + (go_right,)
+            if key not in level:
+                level[key] = ([nd.right for nd in parent] if go_right
+                              else [nd.left for nd in parent])
+        out[name] = np.frombuffer(
+            b"".join([nd._root for nd in level[key]]), np.uint8).reshape(-1, 32)
+    return out

@@ -25,9 +25,10 @@ Layers (see docs/architecture.md, "The block path"):
   host phases execute (``CSTPU_PIPELINE=0`` opts out);
 * ``engine``       — the optimistic fast path + exact-spec replay
   fallback that makes failure behavior literally the spec's
-  (fork families: phase0, and altair/bellatrix with the execution
-  payload run literally inside the snapshot region), plus the
-  speculation window's LIFO drain orchestration.
+  (fork families: phase0, and altair/bellatrix/capella with the
+  execution payload run literally inside the snapshot region and, on
+  capella, the withdrawals-queue dequeue and BLS-to-execution changes),
+  plus the speculation window's LIFO drain orchestration.
 """
 from .attestations import FastPathViolation
 from .engine import apply_signed_blocks, reset_stats, stats

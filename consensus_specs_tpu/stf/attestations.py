@@ -91,7 +91,7 @@ def active_indices(spec, state, epoch: int) -> np.ndarray:
     form of ``get_active_validator_indices``), registry-root-cached."""
     from consensus_specs_tpu.ops.epoch_jax import active_mask, registry_columns
 
-    key = (bytes(state.validators.hash_tree_root()), int(epoch))
+    key = (bulk.registry_key(state.validators), int(epoch))
     hit = _ACTIVE_CACHE.get(key)
     if hit is not None:
         return hit
@@ -138,7 +138,7 @@ def _ctx_lookup_key(spec, state, epoch: int) -> tuple:
     also the context half of every attestation-plan key (below): two
     states sharing it resolve every committee identically."""
     return (
-        bytes(state.validators.hash_tree_root()),
+        bulk.registry_key(state.validators),
         bytes(state.randao_mixes.hash_tree_root()),
         int(epoch),
         _spec_geometry_key(spec),
@@ -184,7 +184,7 @@ def beacon_proposer_index(spec, state):
     seed = bytes(spec.hash(
         spec.get_seed(state, epoch, spec.DOMAIN_BEACON_PROPOSER)
         + spec.uint_to_bytes(spec.uint64(state.slot))))
-    key = (bytes(state.validators.hash_tree_root()), seed,
+    key = (bulk.registry_key(state.validators), seed,
            _spec_geometry_key(spec), int(spec.MAX_EFFECTIVE_BALANCE))
     hit = _PROPOSER_CACHE.get(key)
     if hit is not None:
@@ -210,7 +210,7 @@ def beacon_proposer_index(spec, state):
 
 # -- registry affine-coordinate matrix ---------------------------------------
 
-_AFFINE_MATRIX_CACHE = bulk.RootKeyedCache(2)
+_AFFINE_MATRIX_CACHE = bulk.RootKeyedCache(2, key=bulk.registry_key)
 
 _ZERO_ROW = b"\x00" * 96
 
@@ -236,7 +236,7 @@ def _new_affine_matrix(validators):
     if invalid_pks:
         invalid = np.fromiter(
             (pk in invalid_pks for pk in column), dtype=bool, count=n)
-    return {"mat": mat, "invalid": invalid, "root": bytes(validators.hash_tree_root())}
+    return {"mat": mat, "invalid": invalid}
 
 
 def affine_matrix(validators) -> dict:

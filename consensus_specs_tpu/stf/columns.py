@@ -212,6 +212,21 @@ def flush_balances(state, col: np.ndarray) -> None:
     _BALANCE_PENDING = (state.balances.get_backing(), col)
 
 
+def write_balances(state, col: np.ndarray, indices) -> None:
+    """``flush_balances`` for a staged column that differs from the state's
+    only at ``indices`` (a sweep over a few validators of a large
+    registry): only their chunks are written, and ``col`` is staged on the
+    identity fast path the same way."""
+    from consensus_specs_tpu.ssz import bulk
+
+    global _BALANCE_PENDING
+    bulk.set_packed_uint64_at(state.balances, col, indices)
+    if col.dtype != np.int64:
+        col = col.astype(np.int64)
+    col.setflags(write=False)
+    _BALANCE_PENDING = (state.balances.get_backing(), col)
+
+
 # -- resident device buffers (ISSUE 10) ----------------------------------------
 
 

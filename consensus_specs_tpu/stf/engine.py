@@ -50,6 +50,7 @@ import sys
 import time
 
 from consensus_specs_tpu import faults, telemetry, tracing
+from consensus_specs_tpu.ssz import bulk
 from consensus_specs_tpu.telemetry import histogram, recorder, timeline
 
 from . import columns, pipeline, slot_roots, staging, sync, verify
@@ -63,9 +64,11 @@ from .attestations import (
 # the fork families the fast path covers: phase0's pending-attestation
 # shape, and the altair lineage (participation flags + sync aggregates;
 # bellatrix adds the execution payload, run literally in the snapshot
-# region).  capella+ (withdrawals, bls_to_execution_changes) replay
-# through the literal spec until the engine grows those operations.
-FAST_FORKS = ("phase0", "altair", "bellatrix")
+# region; capella, as this repo snapshots it, adds the withdrawals-queue
+# dequeue and BLS-to-execution changes, whose signatures join the block
+# batch).  Later forks replay through the literal spec.
+FAST_FORKS = ("phase0", "altair", "bellatrix", "capella")
+_UNREADY = "fast path covers phase0/altair/bellatrix/capella + native BLS"
 
 # circuit breaker: after BREAKER_THRESHOLD CONSECUTIVE unexpected
 # fast-path errors (not FastPathViolations — those are the contract
@@ -250,8 +253,7 @@ def _apply_one(spec, state, signed_block, validate_result: bool) -> None:
         if not _fast_path_ready(spec):
             # uncovered forks keep their own kernel substitutions + the
             # facade's deferred per-block batch
-            raise FastPathViolation(
-                "fast path covers phase0/altair/bellatrix + native BLS")
+            raise FastPathViolation(_UNREADY)
         with staging.block_transaction():
             _fast_transition(spec, state, signed_block, validate_result,
                              link=link)
@@ -367,16 +369,15 @@ def _collect_block(spec, state, signed_block, validate_result: bool,
     t2 = time.perf_counter()
 
     # process_block shape of the block's own fork (phase0.py:1149-1154,
-    # altair.py:405-410, bellatrix.py:242-249): header/RANDAO/attestations/
-    # sync aggregate run the vectorized or collect-don't-verify variants
-    # below; the remaining operations are the spec's own functions
+    # altair.py:405-410, bellatrix.py:242-249, capella.py:222-230):
+    # header/RANDAO/attestations/sync aggregate/BLS changes run the
+    # vectorized or collect-don't-verify variants below; the remaining
+    # operations are the spec's own functions
     with tracing.span("host/operations", link=link):
         _header(spec, state, block)
-        if spec.fork == "bellatrix" and spec.is_execution_enabled(state, block.body):
-            # [New in Bellatrix] — literal, inside the snapshot-protected
-            # region: payload checks raise straight into the replay contract
-            spec.process_execution_payload(
-                state, block.body.execution_payload, spec.EXECUTION_ENGINE)
+        if spec.fork in ("bellatrix", "capella") and \
+                spec.is_execution_enabled(state, block.body):
+            _execution_payload(spec, state, block.body.execution_payload)
         _randao_collect(spec, state, block.body, collect, bls_on)
         spec.process_eth1_data(state, block.body)
         t3 = time.perf_counter()
@@ -672,9 +673,7 @@ def _apply_pipelined(spec, state, signed_blocks, validate_result: bool):
         if not ready:
             i = settle_then_replay(
                 "fast_path_unready",
-                FastPathViolation(
-                    "fast path covers phase0/altair/bellatrix + native BLS"),
-                rec)
+                FastPathViolation(_UNREADY), rec)
             continue
         spec_keys = (frozenset().union(*(p.keys_set for p in window))
                      if window else None)
@@ -754,6 +753,88 @@ def _randao_collect(spec, state, body, collect, bls_on) -> None:
     _SITE_RANDAO()  # post-mix-write: rollback must restore randao_mixes
 
 
+def _execution_payload(spec, state, payload) -> None:
+    """The block's execution payload, literal inside the snapshot-protected
+    region, so payload checks raise straight into the replay contract:
+    bellatrix's process_execution_payload; on capella, first the
+    withdrawals dequeue (``_withdrawals``), then capella's
+    process_execution_payload, whose cached header roots the withdrawals."""
+    if spec.fork != "capella":
+        spec.process_execution_payload(state, payload, spec.EXECUTION_ENGINE)
+        return
+    with tracing.span("stf/withdrawals"):
+        _withdrawals(spec, state, payload)
+        spec.process_execution_payload(state, payload, spec.EXECUTION_ENGINE)
+
+
+def _withdrawals(spec, state, payload) -> None:
+    """process_withdrawals (capella.py:233-243): the payload must carry the
+    queue's first ``min(MAX_WITHDRAWALS_PER_PAYLOAD, len(queue))`` entries
+    in order (equal SSZ roots), and the queue keeps the rest as one
+    subtree rebuild over the remaining entries' backings."""
+    queue = state.withdrawals_queue
+    count = min(int(spec.MAX_WITHDRAWALS_PER_PAYLOAD), len(queue))
+    withdrawals = payload.withdrawals
+    if len(withdrawals) != count:
+        raise FastPathViolation("withdrawals != dequeued count")
+    for i in range(count):
+        if bytes(queue[i].hash_tree_root()) != bytes(withdrawals[i].hash_tree_root()):
+            raise FastPathViolation("withdrawal != dequeued entry")
+    if count:
+        state.withdrawals_queue = bulk.composite_list_tail(queue, count)
+    tracing.count("stf.withdrawals", count)
+
+
+def _bls_to_execution_changes(spec, state, changes, collect, bls_on) -> None:
+    """process_bls_to_execution_change (capella.py:293-313) for each of the
+    block's changes in order: the index and BLS-credential checks, the
+    signature as one batch entry (a bad one fails the batch and the block
+    replays literally), and the credential write.  The writes touch only
+    ``withdrawal_credentials``, so the registry's cached columns are
+    re-registered under its new root with the prefix column updated, and
+    its pubkey-derived caches carry over (``bulk.note_withdrawal_writes``):
+    the next block reads them without a registry walk."""
+    if len(changes) == 0:
+        return
+    from consensus_specs_tpu.ops.epoch_jax import columns_after_writes, registry_columns
+
+    with tracing.span("stf/bls_changes"):
+        validators = state.validators
+        count = len(validators)
+        bls_prefix = bytes(spec.BLS_WITHDRAWAL_PREFIX)
+        eth1_prefix = bytes(spec.ETH1_ADDRESS_WITHDRAWAL_PREFIX)
+        domain = (bytes(spec.get_domain(state, spec.DOMAIN_BLS_TO_EXECUTION_CHANGE))
+                  if bls_on else None)
+        cols = registry_columns(state)
+        key = bulk.registry_key(validators)
+        written = []
+        for signed in changes:
+            change = signed.message
+            index = int(change.validator_index)
+            if not index < count:
+                raise FastPathViolation("BLS change: validator index out of range")
+            validator = validators[index]
+            credentials = bytes(validator.withdrawal_credentials)
+            if credentials[:1] != bls_prefix:
+                raise FastPathViolation("BLS change: credentials not BLS")
+            pubkey = bytes(change.from_bls_pubkey)
+            if credentials[1:] != hashlib.sha256(pubkey).digest()[1:]:
+                raise FastPathViolation("BLS change: from_bls_pubkey mismatch")
+            if bls_on:
+                # compute_signing_root: sha256(object root || domain)
+                signing_root = hashlib.sha256(
+                    bytes(change.hash_tree_root()) + domain).digest()
+                collect(pubkey, 1, lambda pk=pubkey: _single_affine(pk),
+                        signing_root, bytes(signed.signature))
+            validator.withdrawal_credentials = (
+                eth1_prefix + b"\x00" * 11 + bytes(change.to_execution_address))
+            written.append(index)
+        columns_after_writes(state, cols,
+                             withdrawal_prefix=(written, eth1_prefix[0]))
+        bulk.note_withdrawal_writes(key, validators)
+    tracing.count("stf.bls_changes", len(changes))
+
+
 def _operations(spec, state, body, collect, bls_on, altair_lineage) -> None:
     """process_operations (phase0.py:1196-1208; altair inherits the same
     dispatch shape) with the attestation loop replaced by the whole-block
@@ -773,6 +854,9 @@ def _operations(spec, state, body, collect, bls_on, altair_lineage) -> None:
         spec.process_deposit(state, operation)
     for operation in body.voluntary_exits:
         spec.process_voluntary_exit(state, operation)
+    if spec.fork == "capella":
+        _bls_to_execution_changes(spec, state, body.bls_to_execution_changes,
+                                  collect, bls_on)
 
 
 def _attestations(spec, state, attestations, collect, bls_on,
@@ -825,7 +909,7 @@ def _attestations_inner(spec, state, attestations, collect, bls_on) -> None:
     proposer_index = beacon_proposer_index(spec, state)
     current_epoch = resolver.current_epoch
     validators = state.validators
-    registry_root = bytes(validators.hash_tree_root())
+    registry_root = bulk.registry_key(validators)
     domains = _attester_domains(spec, state, resolver) if bls_on else None
     state_slot = state.slot
     cur_justified = state.current_justified_checkpoint
@@ -947,7 +1031,7 @@ def _attestations_inner_altair(spec, state, attestations, collect, bls_on) -> No
     proposer_index = beacon_proposer_index(spec, state)
     current_epoch = resolver.current_epoch
     validators = state.validators
-    registry_root = bytes(validators.hash_tree_root())
+    registry_root = bulk.registry_key(validators)
     domains = _attester_domains(spec, state, resolver) if bls_on else None
 
     # participation mirrors: staged views of the resident columns, read

@@ -62,13 +62,13 @@ def sync_committee_rows(spec, state) -> np.ndarray:
     ``all_pubkeys.index(pubkey)`` per seat — first occurrence wins).
     Memoized per (registry, committee) version: one resolution per sync
     period unless the registry changes under it."""
-    key = (bytes(state.validators.hash_tree_root()),
+    from consensus_specs_tpu.ssz import bulk
+
+    key = (bulk.registry_key(state.validators),
            bytes(state.current_sync_committee.hash_tree_root()))
     hit = _SYNC_ROWS_CACHE.get(key)
     if hit is not None:
         return hit
-    from consensus_specs_tpu.ssz import bulk
-
     index_of = bulk.cached_pubkey_index(state.validators)
     pubkeys = state.current_sync_committee.pubkeys
     try:
@@ -138,7 +138,7 @@ def process_sync_aggregate(spec, state, sync_aggregate, collect, bls_on) -> None
             signing_root = spec.compute_signing_root(
                 spec.get_block_root_at_slot(state, spec.Slot(previous_slot)),
                 domain)
-            registry_root = bytes(state.validators.hash_tree_root())
+            registry_root = bulk.registry_key(state.validators)
             validators = state.validators
             collect(registry_root + participant_rows.tobytes(),
                     len(participant_rows),

@@ -70,8 +70,12 @@ def test_coverage_queries():
     assert mirror_registry.coverage(
         "process_slots", "phase0") == "mirror:slot-advance"
     assert mirror_registry.coverage("process_deposit", "phase0") == "literal"
-    # capella is off the fast path: the ISSUE's seeded gap stays a gap
-    assert mirror_registry.coverage("process_withdrawals", "capella") is None
+    # capella is on the fast path: its new operations are declared mirrors
+    assert mirror_registry.coverage(
+        "process_withdrawals", "capella") == "mirror:withdrawals-dequeue"
+    assert mirror_registry.coverage(
+        "process_full_withdrawals", "capella") == "mirror:full-withdrawals-sweep"
+    assert mirror_registry.coverage("process_withdrawals", "eip4844") is None
 
 
 def test_extra_file_deps_cover_pinned_chains():

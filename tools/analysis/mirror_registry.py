@@ -63,9 +63,10 @@ OBLIGATED_PREFIXES: Tuple[str, ...] = ("process_", "verify_", "on_")
 # spec function with no assert/raise statements.
 _NO_RAISES = "e3b0c44298fc1c149afbf4c8996fb92427ae41e4649b934ca495991b7852b855"
 
-_MAINLINE = ("phase0", "altair", "bellatrix")
+_MAINLINE = ("phase0", "altair", "bellatrix", "capella")
 _ALTAIR_ON = ("altair", "bellatrix", "capella")
-_ALL = ("phase0", "altair", "bellatrix", "capella")
+# before capella's process_operations (which adds BLS-to-execution changes)
+_PRE_CAPELLA = ("phase0", "altair", "bellatrix")
 
 
 @dataclass(frozen=True)
@@ -184,15 +185,59 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         qualname="_operations",
         pins=(
             SpecPin(
-                "process_operations", _MAINLINE,
+                "process_operations", _PRE_CAPELLA,
                 "99da53dcefb9e624b16c9e256bbc00042f8f7086ab4e663c4da85a7675ee454e",
+                1,
+                "4f198dcc492a8114d1dae7ff354d549eafbd824d21edc06c76dabf9283b67d1d",
+                ("assert len(body.deposits) == min(",)),
+            SpecPin(
+                "process_operations", ("capella",),
+                "9660e60369dd73f28aae37fdcecbde832bc4f53cf2f4e3a74fbebe7b107afff0",
                 1,
                 "4f198dcc492a8114d1dae7ff354d549eafbd824d21edc06c76dabf9283b67d1d",
                 ("assert len(body.deposits) == min(",)),
         ),
         description="process_operations with the attestation loop swapped "
-        "for the vectorized whole-block path; other operation loops call "
+        "for the vectorized whole-block path and capella's BLS changes "
+        "for _bls_to_execution_changes; other operation loops call "
         "spec.process_* literally.",
+    ),
+    MirrorSpec(
+        name="withdrawals-dequeue",
+        module=f"{_PKG}.stf.engine",
+        qualname="_withdrawals",
+        pins=(
+            SpecPin(
+                "process_withdrawals", ("capella",),
+                "4c4314d18a60307aebc6482a2d2840a06b8938349834513a0fb2aa4d42d3c1d9",
+                2,
+                "9d93745f75650442b965afda6ba5147be66481d57987a6e13c2ade24158da439",
+                ("withdrawals != dequeued count",
+                 "withdrawal != dequeued entry")),
+        ),
+        description="capella process_withdrawals: the payload's entries "
+        "against the queue head by SSZ root (the spec's container "
+        "equality), the dequeue as one subtree refill.",
+    ),
+    MirrorSpec(
+        name="bls-to-execution-change",
+        module=f"{_PKG}.stf.engine",
+        qualname="_bls_to_execution_changes",
+        pins=(
+            SpecPin(
+                "process_bls_to_execution_change", ("capella",),
+                "75281a041f811f8d0b75eb170bf0ca3199810c8e9c4aecb9e142fe8aa959f511",
+                4,
+                "a42aa94300a89e45967061c7f0654f741a42d4978c8a56f1e1ed527ed110bcd8",
+                ("validator index out of range",
+                 "credentials not BLS",
+                 "from_bls_pubkey mismatch",
+                 None)),
+        ),
+        description="capella process_bls_to_execution_change per change in "
+        "order: index and credential asserts transcribed, the signature "
+        "deferred into the block batch (None guard: a bad one fails the "
+        "batch and replays literally), the credential write.",
     ),
     MirrorSpec(
         name="attestations-phase0",
@@ -220,7 +265,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         qualname="_attestations_inner_altair",
         pins=(
             SpecPin(
-                "process_attestation", ("altair", "bellatrix"),
+                "process_attestation", _ALTAIR_ON,
                 "55fbc8605211de2947e1279200fb5529594021d8cb16417c8bebdd68a3e136c2",
                 6,
                 "20ddbb76ef88f04e1f0d7399a4ea663cb199f13442d7f3e72e9970715da91c2b",
@@ -238,7 +283,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         pins=(
             SpecPin(
                 "get_attestation_participation_flag_indices",
-                ("altair", "bellatrix"),
+                _ALTAIR_ON,
                 "54d35d7bcc27cc3c17f1a97f970775598cd625cc1a19790fcbfe72be715e49e8",
                 1,
                 "f4318883418141630910c4914c2addd665652c6a31a166d5ab9d7c1c748687c5",
@@ -331,7 +376,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
                  "aggregation bits != committee size",
                  None, None, None)),
             SpecPin(
-                "process_attestation", ("altair", "bellatrix"),
+                "process_attestation", _ALTAIR_ON,
                 "55fbc8605211de2947e1279200fb5529594021d8cb16417c8bebdd68a3e136c2",
                 6,
                 "20ddbb76ef88f04e1f0d7399a4ea663cb199f13442d7f3e72e9970715da91c2b",
@@ -368,7 +413,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         qualname="process_sync_aggregate",
         pins=(
             SpecPin(
-                "process_sync_aggregate", ("altair", "bellatrix"),
+                "process_sync_aggregate", _ALTAIR_ON,
                 "26307ed96010cd9f29a403c55c628de59c465903c923df3a6a8b6bd1a12f814c",
                 1,
                 "aeca6b7e2e1b347c032001a2bbca15d8afee4c124139d076d8bb74694ae777bc",
@@ -476,7 +521,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         qualname="total_active_balance",
         pins=(
             SpecPin(
-                "get_total_active_balance", _ALL,
+                "get_total_active_balance", _MAINLINE,
                 "ef640e5238ec0462f4c6c6da7d02f34b52e1b92f4b24f22bad4acaf7ac9654a9",
                 0, _NO_RAISES, ()),
         ),
@@ -489,7 +534,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         qualname="active_validator_indices",
         pins=(
             SpecPin(
-                "get_active_validator_indices", _ALL,
+                "get_active_validator_indices", _MAINLINE,
                 "7f0fb8053d6737f8237785d22b188391b522898a9856486f6e48bedc34127fd3",
                 0, _NO_RAISES, ()),
         ),
@@ -502,7 +547,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         qualname="effective_balance_updates",
         pins=(
             SpecPin(
-                "process_effective_balance_updates", _ALL,
+                "process_effective_balance_updates", _MAINLINE,
                 "c7d2ce8328c3bd5a31630119a9cd6f74df9d7334fa4f6c199c8e244c9943ebbe",
                 0, _NO_RAISES, ()),
         ),
@@ -515,7 +560,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         qualname="registry_updates",
         pins=(
             SpecPin(
-                "process_registry_updates", _ALL,
+                "process_registry_updates", _MAINLINE,
                 "7e24127a803379dd79f5287c3301599c98a3c6c45c9d68c8667153d8c58bbc46",
                 0, _NO_RAISES, ()),
         ),
@@ -634,7 +679,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         qualname="_install_optimizations.compute_committee",
         pins=(
             SpecPin(
-                "compute_committee", _ALL,
+                "compute_committee", _MAINLINE,
                 "aed4517144e5f175520771da6108e78998707bd44b2b85f52b1d896be9bdfc24",
                 0, _NO_RAISES, ()),
         ),
@@ -647,7 +692,7 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         qualname="_install_attestation_pubkey_column.is_valid_indexed_attestation",
         pins=(
             SpecPin(
-                "is_valid_indexed_attestation", _ALL,
+                "is_valid_indexed_attestation", _MAINLINE,
                 "b7f57dbe3ee4dbfff347de22107551666d9724025f79100d1627b6bb3ce797ec",
                 0, _NO_RAISES, ()),
         ),
@@ -715,6 +760,29 @@ MIRRORS: Tuple[MirrorSpec, ...] = (
         ),
         description="get_attestation_deltas adapter returning the "
         "epoch_jax kernel's rewards/penalties as spec Gwei lists.",
+    ),
+    # ---- ops/withdrawals.py: capella's epoch sweep ------------------------
+    MirrorSpec(
+        name="full-withdrawals-sweep",
+        module=f"{_PKG}.ops.withdrawals",
+        qualname="full_withdrawals",
+        pins=(
+            SpecPin(
+                "process_full_withdrawals", ("capella",),
+                "2fbe3e8889ab9048d5b77883d11a3dab206421ae05ff2cd9b4e1804a2b83de03",
+                0, _NO_RAISES, ()),
+            SpecPin(
+                "is_fully_withdrawable_validator", ("capella",),
+                "d1311b98451f8a123ca201581e8163c35ca9ab3561ffd3e252b4fbe2e745656b",
+                0, _NO_RAISES, ()),
+            SpecPin(
+                "withdraw_balance", ("capella",),
+                "4aca23a8cc5165eba6cd386670d68da3d1eda465e31bbdf642d137a29da68518",
+                0, _NO_RAISES, ()),
+        ),
+        description="capella process_full_withdrawals: the eligibility test "
+        "as one mask over the registry columns, withdraw_balance and the "
+        "fully_withdrawn_epoch write for the selected validators only.",
     ),
     # ---- forkchoice/batch.py ----------------------------------------------
     MirrorSpec(
@@ -799,7 +867,7 @@ LITERALS: Tuple[LiteralSpec, ...] = (
                 "engine loops spec.process_deposit verbatim"),
     LiteralSpec("process_voluntary_exit", _MAINLINE,
                 "engine loops spec.process_voluntary_exit verbatim"),
-    LiteralSpec("process_execution_payload", ("bellatrix",),
+    LiteralSpec("process_execution_payload", ("bellatrix", "capella"),
                 "literal-inside-snapshot: the engine replays the spec "
                 "body (engine pass, payload checks) inside the snapshot "
                 "region rather than mirroring it"),
@@ -813,7 +881,7 @@ LITERALS: Tuple[LiteralSpec, ...] = (
                 "append-only epoch bookkeeping, spec body runs as-is"),
     LiteralSpec("process_participation_record_updates", ("phase0",),
                 "phase0 attestation-record rotation, spec body runs as-is"),
-    LiteralSpec("process_sync_committee_updates", ("altair", "bellatrix"),
+    LiteralSpec("process_sync_committee_updates", _ALTAIR_ON,
                 "periodic committee rotation, spec body runs as-is"),
 )
 

@@ -54,16 +54,21 @@ PROTECTED_REGION: Dict[str, FrozenSet[str]] = {
         # that owns their ordering
         "_apply_pipelined", "_begin_block", "_collect_block",
         "_unwind_pending",
+        # capella's block operations, dispatched from _collect_block and
+        # _operations inside the same region: the withdrawals-queue
+        # dequeue and the BLS-to-execution credential writes
+        "_withdrawals", "_bls_to_execution_changes",
     }),
     "slot_roots.py": frozenset({"process_slots", "_process_slot"}),
     # sync.py's writers run only from _fast_transition, inside the
     # snapshot region (altair-lineage sync-aggregate rewards)
     "sync.py": frozenset({"process_sync_aggregate", "_apply_rewards"}),
-    # columns.py's state writers are the staged-view flushes (ISSUE 8/10):
-    # called from _attestations_inner_altair (snapshot region) and the
-    # epoch phases (inside process_slots' epoch boundary, also
-    # snapshot-protected); the read-side helpers never write
-    "columns.py": frozenset({"flush", "flush_balances"}),
+    # columns.py's state writers are the staged-view flushes and the sparse
+    # balance write of capella's epoch sweep: called from
+    # _attestations_inner_altair (snapshot region) and the epoch phases
+    # (inside process_slots' epoch boundary, also snapshot-protected); the
+    # read-side helpers never write
+    "columns.py": frozenset({"flush", "flush_balances", "write_balances"}),
 }
 
 
